@@ -2,10 +2,11 @@
 from repro.configs.base import (
     ARCH_IDS, PAPER_IDS, SHAPES, ArchConfig, MoEConfig, SSMConfig,
     ShapeConfig, all_archs, all_cells, cells_for, get_arch, reduced, register,
+    sized,
 )
 
 __all__ = [
     "ARCH_IDS", "PAPER_IDS", "SHAPES", "ArchConfig", "MoEConfig", "SSMConfig",
     "ShapeConfig", "all_archs", "all_cells", "cells_for", "get_arch",
-    "reduced", "register",
+    "reduced", "register", "sized",
 ]

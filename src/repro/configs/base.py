@@ -229,6 +229,17 @@ def all_cells() -> List[Tuple[ArchConfig, ShapeConfig]]:
     return [(a, s) for a in all_archs() for s in cells_for(a)]
 
 
+def sized(cfg: ArchConfig, *, full: bool, layers: Optional[int] = None,
+          smoke_layers: int = 2) -> ArchConfig:
+    """The launchers' config.  ``full`` keeps every published width and
+    ``layers`` then cuts depth only; otherwise the tiny ``reduced``
+    config at ``layers`` (default ``smoke_layers``)."""
+    if full:
+        return cfg if layers is None else dataclasses.replace(
+            cfg, num_layers=layers)
+    return reduced(cfg, layers=layers or smoke_layers)
+
+
 def reduced(cfg: ArchConfig, *, layers: int = 2, d_model: int = 64,
             vocab: int = 512) -> ArchConfig:
     """A tiny same-family config for CPU smoke tests."""

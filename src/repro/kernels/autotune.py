@@ -2,9 +2,9 @@
 
 Every kernel call needs block sizes (flash: block_q/block_k, SSD: the
 chunk length, fused epilogues: row/col tiles).  The right values depend
-on the backend (MXU alignment on TPU, SM occupancy on GPU, grid-step
-overhead under the CPU interpreter), the dtype and the problem shape —
-so they are resolved through a cache keyed by
+on the backend (MXU alignment on TPU, grid-step overhead under the CPU
+interpreter), the dtype and the problem shape — so they are resolved
+through a table keyed by
 
     (kernel kind, backend, dtype, shape bucket)
 
@@ -13,27 +13,20 @@ lengths, which keep their own identity under the pow2 roof: a length
 that pads and tiles differently must not inherit blocks tuned for the
 exact power (the old pow2-only bucket collided e.g. 1000 onto 1024's
 entry — and served 1000's partial-tail tiling the block tuned for the
-clean shape).  Head/state dims are always keyed exactly.  Resolution
-order:
+clean shape).  Head/state dims are always keyed exactly.
 
-  1. in-memory cache (per process),
-  2. the persisted JSON table (``REPRO_AUTOTUNE_CACHE``, default
-     ``~/.cache/repro/autotune.json``) — the same
-     precompute-once/look-up-forever shape as the template-keyed
-     ProgramCache of DESIGN.md §8,
-  3. the PACKAGED offline table (``autotune_offline.json`` next to this
-     module — measured entries checked into the tree, regenerated by
-     ``python -m repro.kernels.autotune``),
-  4. the deterministic heuristic below.
+On an accelerator, blocks come only from the PACKAGED offline table
+(``autotune_offline.json`` next to this module — measured entries
+checked into the tree, regenerated by ``python -m
+repro.kernels.autotune``) and then the deterministic heuristic below,
+so a chip run's blocks depend on the checkout alone.  On the CPU a
+measured entry wins first: the in-memory cache, then the JSON file
+named by ``REPRO_AUTOTUNE_CACHE`` (nothing is persisted without it).
 
 Measured tuning (``tune_flash``/``tune_ssd``/``tune_fused``) runs ONLY
 when invoked explicitly or when ``REPRO_AUTOTUNE=1`` — CI and the
 zero-recompile warm path always hit the deterministic tables, so
-program-cache keys never depend on wall-clock measurements.  Tuning
-measures each kernel under its PROBED lowering mode
-(``ops.kernel_lowers`` per kind, not a platform aggregate), so on a
-backend that compiles flash but interprets SSD each table entry
-reflects what the hot path will actually run.
+program-cache keys never depend on wall-clock measurements.
 """
 from __future__ import annotations
 
@@ -98,30 +91,21 @@ def _packaged() -> Dict[str, Dict[str, int]]:
     return _PACKAGED
 
 
-def _lowered(kind: str, backend: str) -> bool:
-    from repro.kernels import ops as _ops     # lazy: ops imports us
-    kinds = {"flash": ("flash_fwd", "flash_bwd"),
-             "ssd": ("ssd_fwd", "ssd_bwd"),
-             "fused": ("fused_norm", "fused_qkv")}[kind]
-    return all(_ops.kernel_lowers(k, backend) for k in kinds)
-
-
 def _offline(kind: str, backend: str, dtype,
              shape: Tuple[ShapeEntry, ...]) -> Dict[str, int]:
     """Deterministic fallback — packaged measured entry if one exists
     for this exact key, else a pure function of (kind, capability,
     bucket) so CI and warm_templates() never depend on wall-clock.
 
-    The heuristic discriminator is CAPABILITY (the per-kind lowering
-    probe), not platform: compiled backends get 128 — MXU-aligned,
-    small VMEM working set; every interpreting fallback gets blocks as
-    large as the bucket allows, because per-grid-step overhead
-    dominates the interpreter (measured 2-3x over 128 at 2k sequence).
+    Compiled backends get 128 — MXU-aligned, small VMEM working set;
+    the CPU interpreter gets blocks as large as the bucket allows,
+    because per-grid-step overhead dominates it (measured 2-3x over 128
+    at 2k sequence).
     """
     pkg = _packaged().get(_key(kind, backend, dtype, shape))
     if pkg is not None:
         return dict(pkg)
-    compiled = _lowered(kind, backend)
+    compiled = backend != "cpu"
     seq = _seq_of(shape[0])
     if kind == "flash":
         blk = 128 if compiled else min(512, _bucket(seq))
@@ -138,22 +122,18 @@ def _offline(kind: str, backend: str, dtype,
 # The cache
 # ----------------------------------------------------------------------
 class AutotuneCache:
-    """(kind, backend, dtype, bucket) -> block config, with a persisted
-    JSON table behind the in-memory dict."""
+    """(kind, backend, dtype, bucket) -> block config, with an optional
+    persisted JSON table (``path``, default ``$REPRO_AUTOTUNE_CACHE``)
+    behind the in-memory dict."""
 
     def __init__(self, path: Optional[str] = None):
-        if path is None:
-            path = os.environ.get(
-                _ENV_PATH,
-                os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                             "autotune.json"))
-        self.path = path
+        self.path = path or os.environ.get(_ENV_PATH)
         self._mem: Dict[str, Dict[str, int]] = {}
         self._disk_loaded = False
 
     # -- persistence ---------------------------------------------------
     def _load_disk(self) -> None:
-        if self._disk_loaded:
+        if self._disk_loaded or not self.path:
             return
         self._disk_loaded = True
         try:
@@ -169,6 +149,8 @@ class AutotuneCache:
         """Atomically persist the current table (tmp + rename), merged
         over what is already on disk — a fresh process tuning ONE shape
         must not clobber previously persisted entries."""
+        if not self.path:
+            return
         self._load_disk()
         os.makedirs(os.path.dirname(self.path) or ".", exist_ok=True)
         tmp = self.path + ".tmp"
@@ -214,36 +196,40 @@ def tuning_enabled() -> bool:
     return os.environ.get(_ENV_ENABLE, "") == "1"
 
 
+def _config(kind: str, backend: str, dtype,
+            shape: Tuple[ShapeEntry, ...], tune) -> Dict[str, int]:
+    """Measured entries (and REPRO_AUTOTUNE=1 tuning) steer the CPU
+    only; an accelerator reads the packaged table and the heuristic."""
+    if backend == "cpu":
+        cfg = _CACHE.peek(kind, backend, dtype, shape)
+        if cfg is None and tuning_enabled():
+            cfg = tune()
+        if cfg is not None:
+            return cfg
+    return _offline(kind, backend, dtype, shape)
+
+
 def flash_config(backend: str, dtype, seq_len: int, head_dim: int
                  ) -> Dict[str, int]:
-    shape = (shape_bucket(seq_len), head_dim)
-    cfg = _CACHE.peek("flash", backend, dtype, shape)
-    if cfg is None and tuning_enabled():
-        cfg = tune_flash(backend, dtype, seq_len, head_dim)
-    return cfg if cfg is not None else _CACHE.get("flash", backend, dtype,
-                                                  shape)
+    return _config("flash", backend, dtype,
+                   (shape_bucket(seq_len), head_dim),
+                   lambda: tune_flash(backend, dtype, seq_len, head_dim))
 
 
 def ssd_config(backend: str, dtype, seq_len: int, head_dim: int,
                state: int) -> Dict[str, int]:
-    shape = (shape_bucket(seq_len), head_dim, state)
-    cfg = _CACHE.peek("ssd", backend, dtype, shape)
-    if cfg is None and tuning_enabled():
-        cfg = tune_ssd(backend, dtype, seq_len, head_dim, state)
-    return cfg if cfg is not None else _CACHE.get("ssd", backend, dtype,
-                                                  shape)
+    return _config("ssd", backend, dtype,
+                   (shape_bucket(seq_len), head_dim, state),
+                   lambda: tune_ssd(backend, dtype, seq_len, head_dim,
+                                    state))
 
 
 def fused_config(backend: str, dtype, rows: int, cols: int
                  ) -> Dict[str, int]:
     """Row/col tiles for the fused epilogues (kernels/fused.py); keyed
     by (token-rows bucket, output cols)."""
-    shape = (shape_bucket(rows), cols)
-    cfg = _CACHE.peek("fused", backend, dtype, shape)
-    if cfg is None and tuning_enabled():
-        cfg = tune_fused(backend, dtype, rows, cols)
-    return cfg if cfg is not None else _CACHE.get("fused", backend, dtype,
-                                                  shape)
+    return _config("fused", backend, dtype, (shape_bucket(rows), cols),
+                   lambda: tune_fused(backend, dtype, rows, cols))
 
 
 # ----------------------------------------------------------------------

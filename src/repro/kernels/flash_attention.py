@@ -1,15 +1,12 @@
 """Causal GQA flash-attention forward AND backward — single-writer
-Pallas kernels that lower compiled on Mosaic (TPU) and Triton (GPU).
+Pallas kernels, compiled by Mosaic on TPU and interpreted on the CPU.
 
-PR 5's kernels were Mosaic-only: the online-softmax state (m, l, acc)
-and the dq/dkv accumulators lived in VMEM scratch carried across a
-trailing kv/q grid axis, legal solely because Mosaic executes the grid
-sequentially — Triton's parallel grid would corrupt them, so GPU had to
-interpret.  This restructure moves every reduction axis INTO the kernel
-body (kernels/gridcheck.py enforces the discipline):
+Every reduction axis lives INSIDE the kernel body, so no output block
+is written from more than one grid cell and the whole grid may be
+declared parallel (kernels/gridcheck.py enforces the discipline):
 
-    fwd : grid (B, H, q_blocks) — all parallel.  One ``fori_loop`` over
-          kv blocks carries (acc, m, l) as loop values; k/v are whole-
+    fwd : grid (B, H, q_blocks).  One ``fori_loop`` over kv blocks
+          carries (acc, m, l) as loop values; k/v are whole-
           (padded-)sequence VMEM refs sliced with ``pl.ds``.
     bwd : THREE single-writer calls, each accumulating only along its
           own in-body loop —
@@ -19,23 +16,23 @@ body (kernels/gridcheck.py enforces the discipline):
           dk/dv are emitted at Q-head resolution; the GQA group fold is
           one jnp reshape-sum outside.
 
-No output block is written by more than one grid cell and no scratch
-survives a grid step, so the grid is fully parallel on every backend.
 The loop bounds are data-independent functions of the block row/column:
 causality skips kv blocks above the diagonal, a sliding window skips
-blocks left of it — the same work-skipping the old ``pl.when`` gave.
+blocks left of it.
 
 The backward stays the standard two-pass recompute-free formulation
 (FlashAttention-2 §3.2): the forward saves (out, lse); ``delta`` =
 rowsum(dO ∘ O) is a cheap jnp preprocess; p = exp(s - lse) is rebuilt
 blockwise from the saved lse — no O(S²) probability matrix ever exists,
 unlike the jnp-oracle backward ops.py retains as the parity reference.
+Per-row statistics (lse, delta) travel as ``[B, H, 1, S]`` rows: Mosaic
+tiles the last two block dims (8, 128), which a bare ``[.., block_q]``
+block violates.
 
-Block shapes default to (128, 128) so the MXU/tensor cores see aligned
-GEMMs; the whole-sequence k/v refs cost S·D·4B VMEM each (512 KiB at
-S=2048, D=64), far under budget.  The autotuner (kernels/autotune.py)
-picks larger q/k blocks where grid overhead dominates (e.g. the CPU
-interpreter).
+Block shapes default to (128, 128) so the MXU sees aligned GEMMs; the
+whole-sequence k/v refs cost S·D·4B VMEM each (512 KiB at S=2048,
+D=64), far under budget.  The autotuner (kernels/autotune.py) picks
+larger q/k blocks where grid overhead dominates (the CPU interpreter).
 """
 from __future__ import annotations
 
@@ -130,7 +127,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
          jnp.zeros((block_q, 1), jnp.float32)))
     l = jnp.maximum(l, 1e-20)
     o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0] = (m + jnp.log(l)).reshape(block_q)
+    lse_ref[0, 0] = (m + jnp.log(l)).reshape(1, block_q)
 
 
 def _pad_tr(t: jax.Array, pad: int) -> jax.Array:
@@ -167,11 +164,12 @@ def _fwd_call(q, k, v, *, window: int, block_q: int, block_k: int,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq: (b, h, iq)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b, h, iq: (b, h, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, nq * block_q, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, nq * block_q), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, nq * block_q), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
@@ -211,7 +209,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     S = q.shape[1]
     out, lse = _fwd_call(q, k, v, window=window, block_q=block_q,
                          block_k=block_k, interpret=interpret)
-    return out[:, :, :S].transpose(0, 2, 1, 3), lse[:, :, :S]
+    return out[:, :, :S].transpose(0, 2, 1, 3), lse[:, :, 0, :S]
 
 
 # ----------------------------------------------------------------------
@@ -258,8 +256,8 @@ def _flash_bwd_dk_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
         q_start = iq * block_q
         q = q_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
         g = g_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(q_start, block_q)].reshape(block_q, 1)
-        delta = d_ref[0, 0, pl.ds(q_start, block_q)].reshape(block_q, 1)
+        lse = lse_ref[0, 0, :, pl.ds(q_start, block_q)].reshape(block_q, 1)
+        delta = d_ref[0, 0, :, pl.ds(q_start, block_q)].reshape(block_q, 1)
         p = _recompute_p(q, k, lse, q_start=q_start, k_start=k_start,
                          seq_len=seq_len, window=window)
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())))
@@ -283,7 +281,7 @@ def _flash_bwd_dv_kernel(q_ref, k_ref, g_ref, lse_ref, dv_ref, *,
         q_start = iq * block_q
         q = q_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
         g = g_ref[0, 0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(q_start, block_q)].reshape(block_q, 1)
+        lse = lse_ref[0, 0, :, pl.ds(q_start, block_q)].reshape(block_q, 1)
         p = _recompute_p(q, k, lse, q_start=q_start, k_start=k_start,
                          seq_len=seq_len, window=window)
         return dv + jax.lax.dot_general(p, g, (((0,), (0,)), ((), ())))
@@ -322,16 +320,19 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     gt = _pad_tr(g, Sq - S)
     # delta = rowsum(dO * O) — the cheap preprocessing pass
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.pad(delta.transpose(0, 2, 1), ((0, 0), (0, 0), (0, Sq - S)))
-    lse_p = jnp.pad(lse, ((0, 0), (0, 0), (0, Sq - S)))
+    delta = jnp.pad(delta.transpose(0, 2, 1),
+                    ((0, 0), (0, 0), (0, Sq - S)))[:, :, None]
+    lse_p = jnp.pad(lse, ((0, 0), (0, 0), (0, Sq - S)))[:, :, None]
 
     q_blk = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0))
     q_all = pl.BlockSpec((1, 1, Sq, D), lambda b, h, i: (b, h, 0, 0))
     kv_blk = pl.BlockSpec((1, 1, block_k, D),
                           lambda b, h, i: (b, h // G, i, 0))
     kv_all = pl.BlockSpec((1, 1, Sk, D), lambda b, h, i: (b, h // G, 0, 0))
-    row_blk = pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i))
-    row_all = pl.BlockSpec((1, 1, Sq), lambda b, h, i: (b, h, 0))
+    # per-row statistics ride as [B, H, 1, Sq] rows: Mosaic tiles the
+    # last two block dims (8, 128), which a bare [.., block_q] violates
+    row_blk = pl.BlockSpec((1, 1, 1, block_q), lambda b, h, i: (b, h, 0, i))
+    row_all = pl.BlockSpec((1, 1, 1, Sq), lambda b, h, i: (b, h, 0, 0))
     kv_out = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0))
 
     dq = checked_pallas_call(

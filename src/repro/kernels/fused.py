@@ -13,18 +13,17 @@ Two fusions that sit on the per-template scan-program hot path
     pass over x instead of three, one dispatch instead of six.
 
 Both are single-writer parallel-grid kernels (kernels/gridcheck.py) —
-the fwd AND the custom_vjp bwd — so they lower compiled wherever the
-flash kernels do.  ``dw`` for the norm weight reduces across row blocks
-which live on a parallel grid axis, so the kernel emits one [1, d]
-partial per row block and the cross-block sum happens outside
+the fwd AND the custom_vjp bwd.  ``dw`` for the norm weight reduces
+across row blocks which live on a parallel grid axis, so the kernel
+emits one [1, d] partial per row block (as ``[row_blocks, 1, d]``, a
+legal Mosaic tile) and the cross-block sum happens outside
 (single-writer discipline; same shape as the SSD dA partials).
 
 ``add_rmsnorm_ref`` / ``qkv_ref`` are the XLA formulations: identical
 math in one traced expression, used BOTH as the parity oracles and as
-the runtime fallback wherever the Pallas structure has no compiled
-lowering — an *interpreted* Pallas matmul would lose to XLA by orders
-of magnitude, so interpret-mode fallback means "let XLA fuse it", not
-"run the interpreter" (kernels/ops.py routes this).
+the CPU path — an *interpreted* Pallas matmul would lose to XLA by
+orders of magnitude, so on the CPU kernels/ops.py lets XLA fuse it
+rather than run the interpreter.
 """
 from __future__ import annotations
 
@@ -87,9 +86,13 @@ def _row_call(name, kernel, inputs, out_cols, out_dtypes, *, block_rows,
     in_specs = [row_spec if t.shape[0] != 1 else one_spec for t in padded]
     out_specs, out_shape = [], []
     for cols, dt, is_partial in zip(out_cols, out_dtypes, partial_out):
-        if is_partial:                                 # one row per block
-            out_specs.append(pl.BlockSpec((1, cols), lambda i: (i, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((nm, cols), dt))
+        if is_partial:
+            # one [1, cols] row per block, as [nm, 1, cols]: Mosaic tiles
+            # the last two block dims, and (1, cols) of [nm, cols] is not
+            # a legal tile where nm > 1
+            out_specs.append(pl.BlockSpec((None, 1, cols),
+                                          lambda i: (i, 0, 0)))
+            out_shape.append(jax.ShapeDtypeStruct((nm, 1, cols), dt))
         else:
             out_specs.append(pl.BlockSpec((bm, cols), lambda i: (i, 0)))
             out_shape.append(jax.ShapeDtypeStruct((nm * bm, cols), dt))
@@ -126,7 +129,7 @@ def _add_rmsnorm_p_bwd(eps, block_rows, interpret, saved, g):
         [res, w2, gres, gh], [d, d], [res.dtype, jnp.float32],
         block_rows=block_rows, interpret=interpret,
         partial_out=(False, True))
-    dw = jnp.sum(dwp, axis=0, keepdims=True).astype(w2.dtype)
+    dw = jnp.sum(dwp, axis=0).astype(w2.dtype)
     # res = x + r: both addends receive the full residual cotangent
     return dres, dres, dw
 
@@ -152,8 +155,8 @@ def add_rmsnorm(x: jax.Array, r: jax.Array, w: jax.Array, *,
 
 def add_rmsnorm_ref(x: jax.Array, r: jax.Array, w: jax.Array, *,
                     eps: float = 1e-6) -> Tuple[jax.Array, jax.Array]:
-    """XLA formulation — parity oracle AND the no-lowering fallback
-    (identical math to models/layers.rms_norm applied to x + r)."""
+    """XLA formulation — parity oracle AND the CPU path (identical math
+    to models/layers.rms_norm applied to x + r)."""
     res = x + r
     res32 = res.astype(jnp.float32)
     var = jnp.mean(res32 * res32, axis=-1, keepdims=True)
@@ -257,13 +260,13 @@ def qkv_ref(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
             bv: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """XLA formulation: three dots + bias epilogues in a SINGLE traced
-    expression (one program, epilogues fused) — the no-lowering
-    fallback and the parity oracle versus the Pallas tiles.
+    expression (one program, epilogues fused) — the CPU path and the
+    parity oracle versus the Pallas tiles.
 
     Deliberately NOT the concatenated-weight GEMM: without a tiled
     kernel to exploit the wider N, XLA:CPU runs the wide GEMM slightly
     slower than three narrow ones and pays a full weight copy for the
-    concat plus three slice copies for the split.  The fallback's win
+    concat plus three slice copies for the split.  The CPU path's win
     over the unfused path is program fusion (one dispatch, fused
     epilogues), so it keeps the GEMM shapes the backend prefers."""
     outs = []

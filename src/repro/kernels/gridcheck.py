@@ -1,12 +1,11 @@
 """Grid-write static check for Pallas kernels (DESIGN.md §13).
 
-The PR 5 footgun, turned into an importable assertion: a kernel whose
-output block is written from more than one iteration of a PARALLEL grid
-axis — or whose scratch carries state across one — is only correct on
-backends that execute the grid sequentially (Mosaic).  Triton runs grid
-cells concurrently, so the same structure silently corrupts
-accumulators instead of failing loudly.  Every pallas_call in this
-package is built through ``checked_pallas_call``, which
+A kernel whose output block is written from more than one iteration of
+a PARALLEL grid axis — or whose scratch carries state across one — is
+correct only while that axis happens to run sequentially; Mosaic may
+split a "parallel" axis across TensorCores and corrupt the accumulator.
+Every pallas_call in this package is built through
+``checked_pallas_call``, which
 
   1. numerically probes each output BlockSpec index map and derives the
      *revisit axes* — grid axes along which the map keeps returning the
@@ -17,17 +16,16 @@ package is built through ``checked_pallas_call``, which
      innermost sequential suffix of the grid);
   3. records the verdict in ``REGISTRY`` so tests/CI can audit every
      kernel structure in one sweep;
-  4. injects Mosaic ``dimension_semantics`` from the declaration —
-     parallel axes are declared parallel (Mosaic may distribute them),
-     sequential axes "arbitrary" (Mosaic serializes, which is what
-     makes the carry legal there).
+  4. passes Mosaic ``dimension_semantics`` from the declaration —
+     parallel axes "parallel" (Mosaic may distribute them), sequential
+     axes "arbitrary" (Mosaic serializes, which is what makes the carry
+     legal there).
 
 A kernel with NO revisit axes and NO scratch carry is single-writer:
-every output block is written by exactly one grid cell, so the grid can
-be fully parallel on any backend.  All flash kernels now satisfy this;
-the SSD kernels keep their inter-chunk state carry but declare the
-chunk axis sequential, which Triton serializes and Mosaic already
-guarantees.
+every output block is written by exactly one grid cell, so its whole
+grid may be parallel.  All flash kernels satisfy this; the SSD kernels
+keep their inter-chunk state carry and declare the chunk axis
+sequential.
 
 The probe evaluates index maps at integer grid coordinates (axis 0,
 then 1 and n-1 per axis, others held at 0); maps here are affine or
@@ -42,6 +40,7 @@ import dataclasses
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 class GridWriteError(AssertionError):
@@ -145,10 +144,10 @@ def check_grid_writes(name: str, *, grid: Sequence[int], out_specs,
 
 
 def _mosaic_params(grid: Sequence[int],
-                   sequential_axes: Sequence[int]) -> Dict[str, Any]:
+                   sequential_axes: Sequence[int]) -> pltpu.CompilerParams:
     sems = tuple("arbitrary" if a in sequential_axes else "parallel"
                  for a in range(len(grid)))
-    return dict(mosaic=dict(dimension_semantics=sems))
+    return pltpu.CompilerParams(dimension_semantics=sems)
 
 
 def checked_pallas_call(name: str, kernel, *, grid, in_specs, out_specs,
@@ -174,7 +173,6 @@ def checked_pallas_call(name: str, kernel, *, grid, in_specs, out_specs,
     if scratch_shapes:
         kwargs["scratch_shapes"] = list(scratch_shapes)
     if not interpret:
-        # semantics are a Mosaic-side contract; the interpreter ignores
-        # them and some jax versions reject the kwarg there.
+        # a Mosaic-side contract; the interpreter has no use for it
         kwargs["compiler_params"] = _mosaic_params(grid, sequential_axes)
     return pl.pallas_call(kernel, **kwargs)

@@ -1,39 +1,32 @@
 """Jitted public wrappers around the Pallas kernels (DESIGN.md §11, §13).
 
-Backend gating is a measured LOWERING PROBE, not a platform list: for
-each kernel structure (``KERNEL_KINDS``) the first query on the live
-backend try-compiles a small representative instance and caches the
-verdict one-shot per (kind, backend); kernels whose structure fails to
-lower fall back to interpret (or the XLA-fused formulation, for the
-fused epilogues) PER KERNEL, not per platform.  For backends that are
-not the process default (nothing to compile against), a static
-capability table answers: the restructured single-writer kernels lower
-on Mosaic and Triton; the SSD carry still rides ``pltpu.VMEM`` scratch,
-which Triton has no lowering for, so GPU interprets the SSD pair only.
-PR 5's ``COMPILED_BACKENDS = ("tpu",)`` — which forced GPU to interpret
-EVERYTHING because the old grid-scratch structure would be corrupted by
-Triton's parallel grid — is gone; the restructure (flash_attention.py,
-ssd.py, gridcheck.py) is what made the probe meaningful.
+Where a kernel runs is decided by the backend, with no silent fallback:
+
+  * CPU has no compiled Pallas.  The attention/SSD kernels run under
+    the Pallas interpreter, and the fused epilogues use their XLA
+    formulation (an interpreted elementwise kernel would lose to XLA's
+    own fusion).
+  * Every other backend runs every kernel compiled.  The first query
+    per kind on the live backend try-compiles a small representative
+    instance (``kernel_lowers``); a kernel that fails to lower raises
+    ``KernelLoweringError`` with the compiler's message, so a chip run
+    never quietly degrades to the interpreter or the XLA substitute.
 
 Because lowering is resolved at trace time, it is part of program
 identity: any cache of traced programs must carry
-``backend_signature()`` — now (backend, process topology, per-kind
-lowering plan) — in its key (the runtime's ProgramCache does; see
+``backend_signature()`` — (backend, process topology, per-kind lowering
+plan) — in its key (the runtime's ProgramCache does; see
 runtime/executor.py).
-Otherwise a program traced under the CPU default and reused on an
-accelerator mesh would silently run the Python interpreter at device
-speed's expense.
 
 Both kernels carry a ``jax.custom_vjp`` whose backward is ALSO a Pallas
-kernel (kernels/flash_attention.py, kernels/ssd.py), with separate
-fwd/bwd interpret flags so e.g. a backend that lowers the forward but
-not the backward still compiles half the pair.  The pure-jnp oracles
-(kernels/ref.py) remain the parity references — ``oracle_attention_vjp``
-/ ``oracle_ssd_vjp`` are the OLD recompute-through-oracle backward
-rules, retained for tests and the roofline benchmark's baseline.
+kernel (kernels/flash_attention.py, kernels/ssd.py).  The pure-jnp
+oracles (kernels/ref.py) remain the parity references —
+``oracle_attention_vjp`` / ``oracle_ssd_vjp`` are the OLD
+recompute-through-oracle backward rules, retained for tests and the
+roofline benchmark's baseline.
 
 Block sizes default to the autotuner's (backend, dtype, shape-bucket)
-cache (kernels/autotune.py); explicit ``block_q``/``block_k``/``chunk``
+table (kernels/autotune.py); explicit ``block_q``/``block_k``/``chunk``
 arguments override it.
 """
 from __future__ import annotations
@@ -54,66 +47,60 @@ from repro.kernels import ssd as _ssd
 KERNEL_KINDS = ("flash_fwd", "flash_bwd", "ssd_fwd", "ssd_bwd",
                 "fused_norm", "fused_qkv")
 
-#: Capability table for backends that are NOT the process default —
-#: there is nothing to try-compile against, so this is the structural
-#: answer: single-writer parallel-grid kernels (flash fwd/bwd, both
-#: fused epilogues) lower on Mosaic and Triton alike; the SSD pair
-#: still carries dstate in pltpu.VMEM scratch along the sequential
-#: chunk axis, which has no Triton lowering yet.
-_STATIC_LOWERING: Dict[str, Dict[str, bool]] = {
-    "tpu": {k: True for k in KERNEL_KINDS},
-    "gpu": {k: not k.startswith("ssd") for k in KERNEL_KINDS},
-    "cuda": {k: not k.startswith("ssd") for k in KERNEL_KINDS},
-    "rocm": {k: not k.startswith("ssd") for k in KERNEL_KINDS},
-    "cpu": {k: False for k in KERNEL_KINDS},
-}
-
 _LOWERING_CACHE: Dict[Tuple[str, str], bool] = {}
+
+
+class KernelLoweringError(RuntimeError):
+    """A Pallas kernel does not compile on an accelerator backend."""
 
 
 def resolve_backend() -> str:
     return jax.default_backend()
 
 
+def _aval(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
 def _probe_flash_fwd():
-    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
-    k = jnp.zeros((1, 128, 1, 64), jnp.float32)
+    q = _aval((1, 128, 2, 64), jnp.float32)
+    k = _aval((1, 128, 1, 64), jnp.float32)
     _fa.flash_attention.lower(q, k, k, window=0, block_q=128, block_k=128,
                               interpret=False).compile()
 
 
 def _probe_flash_bwd():
-    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
-    k = jnp.zeros((1, 128, 1, 64), jnp.float32)
-    lse = jnp.zeros((1, 2, 128), jnp.float32)
+    q = _aval((1, 128, 2, 64), jnp.float32)
+    k = _aval((1, 128, 1, 64), jnp.float32)
+    lse = _aval((1, 2, 128), jnp.float32)
     _fa.flash_attention_bwd.lower(q, k, k, q, lse, q, window=0,
                                   block_q=128, block_k=128,
                                   interpret=False).compile()
 
 
 def _probe_ssd_fwd():
-    x = jnp.zeros((1, 128, 1, 64), jnp.float32)
-    dt = jnp.zeros((1, 128, 1), jnp.float32)
-    A = jnp.zeros((1,), jnp.float32)
-    B = jnp.zeros((1, 128, 1, 16), jnp.float32)
+    x = _aval((1, 128, 1, 64), jnp.float32)
+    dt = _aval((1, 128, 1), jnp.float32)
+    A = _aval((1,), jnp.float32)
+    B = _aval((1, 128, 1, 16), jnp.float32)
     _ssd.ssd_fwd.lower(x, dt, A, B, B, chunk=128,
                        interpret=False).compile()
 
 
 def _probe_ssd_bwd():
-    x = jnp.zeros((1, 128, 1, 64), jnp.float32)
-    dt = jnp.zeros((1, 128, 1), jnp.float32)
-    A = jnp.zeros((1,), jnp.float32)
-    B = jnp.zeros((1, 128, 1, 16), jnp.float32)
-    cst = jnp.zeros((1, 1, 1, 64, 16), jnp.float32)
-    gst = jnp.zeros((1, 1, 64, 16), jnp.float32)
+    x = _aval((1, 128, 1, 64), jnp.float32)
+    dt = _aval((1, 128, 1), jnp.float32)
+    A = _aval((1,), jnp.float32)
+    B = _aval((1, 128, 1, 16), jnp.float32)
+    cst = _aval((1, 1, 1, 64, 16), jnp.float32)
+    gst = _aval((1, 1, 64, 16), jnp.float32)
     _ssd.ssd_bwd.lower(x, dt, A, B, B, cst, x, gst, chunk=128,
                        interpret=False).compile()
 
 
 def _probe_fused_norm():
-    x = jnp.zeros((128, 64), jnp.float32)
-    w = jnp.zeros((64,), jnp.float32)
+    x = _aval((128, 64), jnp.float32)
+    w = _aval((64,), jnp.float32)
 
     def f(x, r, w):
         res, h = _fused.add_rmsnorm(x, r, w, block_rows=128,
@@ -124,8 +111,8 @@ def _probe_fused_norm():
 
 
 def _probe_fused_qkv():
-    x = jnp.zeros((128, 64), jnp.float32)
-    w = jnp.zeros((64, 128), jnp.float32)
+    x = _aval((128, 64), jnp.float32)
+    w = _aval((64, 128), jnp.float32)
 
     def f(x, wq, wk, wv):
         q, k, v = _fused.qkv(x, wq, wk, wv, block_m=128, block_n=128,
@@ -146,24 +133,33 @@ _PROBES = {
 
 
 def kernel_lowers(kind: str, backend: Optional[str] = None) -> bool:
-    """One-shot cached lowering probe: True iff ``kind``'s structure
-    compiles on ``backend``.  The live (default) backend is answered by
-    an actual try-compile of a representative instance; other backends
-    by the static capability table."""
+    """True iff ``kind`` runs compiled on ``backend``.
+
+    CPU answers False (interpreter / XLA formulation).  Any other
+    backend answers True or raises: on the live backend a one-shot
+    try-compile of a representative instance must succeed, and its
+    failure raises ``KernelLoweringError`` carrying the compiler's
+    message.  A TPU that is not the live backend answers True — the
+    structures are compiled for a described v5e by
+    tests/test_tpu_compile.py."""
     if kind not in KERNEL_KINDS:
         raise ValueError(f"unknown kernel kind {kind!r}")
     backend = backend or resolve_backend()
+    if backend == "cpu":
+        return False
     key = (kind, backend)
     if key not in _LOWERING_CACHE:
         if backend == jax.default_backend():
             try:
                 _PROBES[kind]()
-                _LOWERING_CACHE[key] = True
-            except Exception:
-                _LOWERING_CACHE[key] = False
-        else:
-            table = _STATIC_LOWERING.get(backend, {})
-            _LOWERING_CACHE[key] = table.get(kind, False)
+            except Exception as e:
+                raise KernelLoweringError(
+                    f"Pallas kernel {kind!r} does not compile on "
+                    f"{backend}: {e}") from e
+        elif backend != "tpu":
+            raise KernelLoweringError(
+                f"no Pallas lowering is maintained for {backend!r}")
+        _LOWERING_CACHE[key] = True
     return _LOWERING_CACHE[key]
 
 
@@ -177,14 +173,6 @@ def lowering_plan(backend: Optional[str] = None
     """Per-kind lowering verdicts, in KERNEL_KINDS order (hashable)."""
     backend = backend or resolve_backend()
     return tuple((k, kernel_lowers(k, backend)) for k in KERNEL_KINDS)
-
-
-def interpret_mode(backend: Optional[str] = None) -> bool:
-    """True iff ANY kernel structure must run under the Pallas
-    interpreter on ``backend`` (the conservative aggregate; per-kernel
-    callers should ask ``kernel_lowers`` directly)."""
-    backend = backend or resolve_backend()
-    return any(not lowered for _, lowered in lowering_plan(backend))
 
 
 def process_topology() -> Tuple[int, int, Tuple[int, ...]]:
@@ -221,27 +209,25 @@ def backend_signature() -> Tuple:
 # ----------------------------------------------------------------------
 # Flash attention
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, window: int, block_q: int, block_k: int,
-           interpret_fwd: bool, interpret_bwd: bool):
+           interpret: bool):
     return _fa.flash_attention(q, k, v, window=window, block_q=block_q,
-                               block_k=block_k, interpret=interpret_fwd)
+                               block_k=block_k, interpret=interpret)
 
 
-def _flash_fwd(q, k, v, window, block_q, block_k, interpret_fwd,
-               interpret_bwd):
+def _flash_fwd(q, k, v, window, block_q, block_k, interpret):
     out, lse = _fa.flash_attention_fwd(
         q, k, v, window=window, block_q=block_q, block_k=block_k,
-        interpret=interpret_fwd)
+        interpret=interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(window, block_q, block_k, interpret_fwd, interpret_bwd,
-               res, g):
+def _flash_bwd(window, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     return _fa.flash_attention_bwd(
         q, k, v, out, lse, g, window=window, block_q=block_q,
-        block_k=block_k, interpret=interpret_bwd)
+        block_k=block_k, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -261,32 +247,32 @@ def flash_attention(q, k, v, window: int = 0,
                                     q.shape[3])
         block_q = block_q or cfg["block_q"]
         block_k = block_k or cfg["block_k"]
-    return _flash(q, k, v, window, block_q, block_k,
-                  not kernel_lowers("flash_fwd", backend),
-                  not kernel_lowers("flash_bwd", backend))
+    compiled = (kernel_lowers("flash_fwd", backend)
+                and kernel_lowers("flash_bwd", backend))
+    return _flash(q, k, v, window, block_q, block_k, not compiled)
 
 
 # ----------------------------------------------------------------------
 # SSD (Mamba2 chunked scan)
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _ssd_p(x, dt, A, B, C, chunk: int, interpret_fwd: bool,
-           interpret_bwd: bool) -> Tuple[jax.Array, jax.Array]:
-    return _ssd.ssd(x, dt, A, B, C, chunk=chunk, interpret=interpret_fwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd_p(x, dt, A, B, C, chunk: int,
+           interpret: bool) -> Tuple[jax.Array, jax.Array]:
+    return _ssd.ssd(x, dt, A, B, C, chunk=chunk, interpret=interpret)
 
 
-def _ssd_fwd(x, dt, A, B, C, chunk, interpret_fwd, interpret_bwd):
+def _ssd_fwd(x, dt, A, B, C, chunk, interpret):
     y, state, cstates = _ssd.ssd_fwd(x, dt, A, B, C, chunk=chunk,
-                                     interpret=interpret_fwd)
+                                     interpret=interpret)
     return (y, state), (x, dt, A, B, C, cstates)
 
 
-def _ssd_bwd(chunk, interpret_fwd, interpret_bwd, res, g):
+def _ssd_bwd(chunk, interpret, res, g):
     x, dt, A, B, C, cstates = res
     gy, gstate = g
     return _ssd.ssd_bwd(x, dt, A, B, C, cstates, gy,
                         gstate.astype(jnp.float32), chunk=chunk,
-                        interpret=interpret_bwd)
+                        interpret=interpret)
 
 
 _ssd_p.defvjp(_ssd_fwd, _ssd_bwd)
@@ -303,9 +289,9 @@ def ssd(x, dt, A, B, C,
     if chunk is None:
         chunk = autotune.ssd_config(backend, x.dtype, x.shape[1],
                                     x.shape[3], B.shape[-1])["chunk"]
-    return _ssd_p(x, dt, A, B, C, chunk,
-                  not kernel_lowers("ssd_fwd", backend),
-                  not kernel_lowers("ssd_bwd", backend))
+    compiled = (kernel_lowers("ssd_fwd", backend)
+                and kernel_lowers("ssd_bwd", backend))
+    return _ssd_p(x, dt, A, B, C, chunk, not compiled)
 
 
 # ----------------------------------------------------------------------
@@ -315,11 +301,10 @@ def fused_add_rmsnorm(x, r, w, eps: float = 1e-6
                       ) -> Tuple[jax.Array, jax.Array]:
     """Fused (res, h) = (x + r, rms_norm(w, x + r)).
 
-    Routed like the attention/SSD kernels: the Pallas kernel where the
-    structure lowers compiled, otherwise the single-expression XLA
-    formulation (an INTERPRETED Pallas elementwise kernel would lose to
-    XLA's own fusion, so the fallback is XLA-level fusion, not the
-    interpreter).  ``w`` must already be in x.dtype.
+    The compiled Pallas kernel on an accelerator; on the CPU the
+    single-expression XLA formulation (an INTERPRETED Pallas elementwise
+    kernel would lose to XLA's own fusion).  ``w`` must already be in
+    x.dtype.
     """
     backend = resolve_backend()
     if kernel_lowers("fused_norm", backend):
@@ -333,12 +318,11 @@ def fused_add_rmsnorm(x, r, w, eps: float = 1e-6
 
 def fused_qkv(x, wq, wk, wv, bq=None, bk=None, bv=None
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused QKV projection, one program either way: Pallas tiles over
-    the concatenated weight (one wide GEMM + bias epilogue) where the
-    structure lowers compiled; a single XLA program of three dots with
-    fused bias epilogues otherwise (XLA:CPU prefers the narrow GEMM
-    shapes — see fused.qkv_ref).  Both eliminate the per-op dispatches
-    and intermediate materialization of the unfused path."""
+    """Fused QKV projection, one program either way: on an accelerator,
+    Pallas tiles over the concatenated weight (one wide GEMM + bias
+    epilogue); on the CPU, a single XLA program of three dots with fused
+    bias epilogues (XLA:CPU prefers the narrow GEMM shapes — see
+    fused.qkv_ref)."""
     backend = resolve_backend()
     if kernel_lowers("fused_qkv", backend):
         rows = x.size // x.shape[-1]

@@ -3,14 +3,15 @@ an explicitly SEQUENTIAL chunk axis.
 
 Grid (batch, heads, chunks), built through ``checked_pallas_call``
 (kernels/gridcheck.py) with the chunk axis declared sequential and the
-inter-chunk SSM state carried in scratch along it.  On Mosaic the grid
-is executed sequentially anyway (the declaration maps to
-``dimension_semantics=("parallel", "parallel", "arbitrary")`` so batch
-and heads may still be distributed); on Triton a sequential
-("arbitrary") innermost axis is serialized, which is what makes the
-[P, N] fp32 scratch carry legal there too — the recurrence costs no HBM
-round-trips on either backend (the classic GPU alternative writes chunk
-states to HBM and runs a separate scan kernel; see DESIGN.md §13).
+inter-chunk SSM state carried in VMEM scratch along it:
+``dimension_semantics=("parallel", "parallel", "arbitrary")``, so batch
+and heads may still be distributed while the [P, N] fp32 recurrence
+costs no HBM round-trips.
+
+Blocks are head-major (``[b, H, S, *]``, transposed outside the call)
+with ``dt`` as a ``[b, H, 1, S]`` lane row: Mosaic tiles the last two
+block dims (8, 128), so a size-1 head axis cannot sit second-minor.
+Mosaic has no ``cumsum``; cumulative sums are triangular matmuls.
 
 Per chunk the kernel computes, entirely in VMEM:
     cum      = cumsum(dt * A)                       [Q,1]
@@ -25,9 +26,8 @@ VMEM for every assigned config (mamba2: P=64, N=128; hymba: P=64, N=16).
 The backward mirrors the recurrence in REVERSE chunk order (index maps
 c -> nc-1-c), carrying the state cotangent dS in the same scratch slot
 the forward carries the state in — the ONLY cross-iteration state.  The
-scalar dA reduction that PR 5 accumulated in scratch and wrote once at
-the last chunk is now a per-chunk partial output ([b, H, nc], one block
-per grid cell — single-writer) summed outside: the kernel has no
+scalar dA reduction is a per-chunk partial output ([b, H, nc, 1, 1],
+one block per grid cell — single-writer) summed outside: the kernel has no
 finalize step and no write that depends on grid position.  It is
 recompute-free in the flash-attention sense: the forward saves only the
 [P, N] state at each chunk BOUNDARY (``ssd_fwd``'s third output, S/Q of
@@ -51,6 +51,27 @@ from repro.kernels.gridcheck import checked_pallas_call
 DEFAULT_CHUNK = 128
 
 
+def _tri(chunk: int):
+    """(lower, upper) [Q, Q] triangles: lower[i, j] = i >= j."""
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return ii >= jj, ii <= jj
+
+
+def _scalar(t):
+    """[1, 1] tile -> scalar.  Mosaic cannot broadcast a [1, 1] vector
+    along sublanes and lanes at once; a scalar splats anywhere."""
+    return jnp.sum(t)
+
+
+def _tri_matvec(mask, v):
+    """``where(mask, 1, 0) @ v`` at full fp32 precision — a cumulative
+    sum of the [Q, 1] column ``v`` (Mosaic has no cumsum primitive)."""
+    return jax.lax.dot_general(jnp.where(mask, 1.0, 0.0), v,
+                               (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
+
+
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
                 *rest, chunk: int):
     # the fwd-for-bwd variant adds a cstates output (the state ENTERING
@@ -65,22 +86,20 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     def _init():
         state_scratch[...] = jnp.zeros_like(state_scratch)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # [Q, P]
-    dt = dt_ref[0].astype(jnp.float32)                 # [Q, 1]
-    A = a_ref[0, 0]                                    # scalar (negative)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)         # [Q, N]
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)         # [Q, N]
+    x = x_ref[0, 0].astype(jnp.float32)                # [Q, P]
+    dt_row = dt_ref[0, 0].astype(jnp.float32)          # [1, Q]
+    dt = dt_row.reshape(chunk, 1)                      # [Q, 1]
+    A = _scalar(a_ref[0])                              # (negative)
+    Bm = b_ref[0, 0].astype(jnp.float32)               # [Q, N]
+    Cm = c_ref[0, 0].astype(jnp.float32)               # [Q, N]
 
-    a = dt * A                                         # [Q, 1]
-    cum = jnp.cumsum(a, axis=0)                        # [Q, 1]
+    tri, _ = _tri(chunk)
+    cum = _tri_matvec(tri, dt * A)                     # [Q, 1] cumsum
 
     # intra-chunk: W[i,j] = exp(cum_i - cum_j) * (C_i . B_j) * dt_j, j <= i
     decay = jnp.exp(cum - cum.reshape(1, chunk))       # [Q, Q]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = ii >= jj
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # [Q, Q]
-    w = jnp.where(tri, cb * decay, 0.0) * dt.reshape(1, chunk)
+    w = jnp.where(tri, cb * decay, 0.0) * dt_row
     y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())))     # [Q, P]
 
     # inter-chunk: y += (C * exp(cum)) @ state^T
@@ -90,13 +109,13 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     c_scaled = Cm * jnp.exp(cum)                       # [Q, N]
     y = y + jax.lax.dot_general(c_scaled, state,
                                 (((1,), (1,)), ((), ())))        # [Q, P]
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: state * exp(cum_Q) + (x ∘ w_last)^T @ B
-    cum_last = cum[chunk - 1]                          # [1]
-    w_last = jnp.exp(cum_last.reshape(1, 1) - cum) * dt           # [Q, 1]
+    cum_last = _scalar(cum[chunk - 1:, :])
+    w_last = jnp.exp(cum_last - cum) * dt              # [Q, 1]
     xw = x * w_last                                    # [Q, P]
-    new_state = (state * jnp.exp(cum_last)[0]
+    new_state = (state * jnp.exp(cum_last)
                  + jax.lax.dot_general(xw, Bm, (((0,), (0,)), ((), ()))))
     state_scratch[...] = new_state
     state_ref[0, 0] = new_state
@@ -106,26 +125,36 @@ def _pad_seq(t: jax.Array, pad: int) -> jax.Array:
     return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
 
 
+def _head_major(x, dt, B, C, pad: int):
+    """[b, S, H, *] -> [b, H, S_p, *] and dt [b, S, H] -> [b, H, 1, S_p]:
+    Mosaic tiles the last two block dims (8, 128), so the head axis
+    cannot sit second-minor with block size 1; dt rides as a lane row."""
+    if pad:
+        x, dt, B, C = (_pad_seq(t, pad) for t in (x, dt, B, C))
+    tr = lambda t: t.transpose(0, 2, 1, 3)
+    return tr(x), dt.transpose(0, 2, 1)[:, :, None, :], tr(B), tr(C)
+
+
 def _ssd_call(x, dt, A, B, C, *, chunk: int, interpret: bool,
               with_cstates: bool):
     b, S, H, P = x.shape
     N = B.shape[-1]
     chunk = min(chunk, max(S, 8))
     pad = (-S) % chunk
-    if pad:
-        x, dt, B, C = (_pad_seq(t, pad) for t in (x, dt, B, C))
     S_p = S + pad
     nc = S_p // chunk
-    a2 = A.reshape(H, 1)
+    xt, dtt, Bt, Ct = _head_major(x, dt, B, C, pad)
+    a3 = A.reshape(H, 1, 1)
 
+    seq = lambda i, h, c: (i, h, c, 0)
     out_specs = [
-        pl.BlockSpec((1, chunk, 1, P), lambda i, h, c: (i, c, h, 0)),
+        pl.BlockSpec((1, 1, chunk, P), seq),
         # final state: every chunk writes the same block — legal only
         # because axis 2 is declared sequential (last write wins)
         pl.BlockSpec((1, 1, P, N), lambda i, h, c: (i, h, 0, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((b, S_p, H, P), x.dtype),
+        jax.ShapeDtypeStruct((b, H, S_p, P), x.dtype),
         jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
     ]
     if with_cstates:
@@ -139,11 +168,11 @@ def _ssd_call(x, dt, A, B, C, *, chunk: int, interpret: bool,
         "ssd_fwd", kernel,
         grid=(b, H, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda i, h, c: (i, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda i, h, c: (i, c, h)),
-            pl.BlockSpec((1, 1), lambda i, h, c: (h, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda i, h, c: (i, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda i, h, c: (i, c, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), seq),
+            pl.BlockSpec((1, 1, 1, chunk), lambda i, h, c: (i, h, 0, c)),
+            pl.BlockSpec((1, 1, 1), lambda i, h, c: (h, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, N), seq),
+            pl.BlockSpec((1, 1, chunk, N), seq),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -151,12 +180,9 @@ def _ssd_call(x, dt, A, B, C, *, chunk: int, interpret: bool,
         interpret=interpret,
         sequential_axes=(2,),
         scratch_carry_axes=(2,),
-    )(x, dt, a2, B, C)
-    if with_cstates:
-        y, state, cstates = outs
-        return y[:, :S], state, cstates
-    y, state = outs
-    return y[:, :S], state, None
+    )(xt, dtt, a3, Bt, Ct)
+    y = outs[0][:, :, :S].transpose(0, 2, 1, 3)
+    return y, outs[1], (outs[2] if with_cstates else None)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -196,29 +222,26 @@ def _ssd_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, gy_ref,
     def _init():
         dstate_scratch[...] = gstate_ref[0, 0]
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # [Q, P]
-    dt = dt_ref[0].astype(jnp.float32)                 # [Q, 1]
-    A = a_ref[0, 0]                                    # scalar
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)         # [Q, N]
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)         # [Q, N]
+    x = x_ref[0, 0].astype(jnp.float32)                # [Q, P]
+    dt_row = dt_ref[0, 0].astype(jnp.float32)          # [1, Q]
+    dt = dt_row.reshape(chunk, 1)                      # [Q, 1]
+    A = _scalar(a_ref[0])
+    Bm = b_ref[0, 0].astype(jnp.float32)               # [Q, N]
+    Cm = c_ref[0, 0].astype(jnp.float32)               # [Q, N]
     S0 = s0_ref[0, 0, 0]                               # [P, N]
-    G = gy_ref[0, :, 0, :].astype(jnp.float32)         # [Q, P]
+    G = gy_ref[0, 0].astype(jnp.float32)               # [Q, P]
     dS1 = dstate_scratch[...]                          # [P, N]
 
-    a = dt * A
-    cum = jnp.cumsum(a, axis=0)                        # [Q, 1]
-    dt_row = dt.reshape(1, chunk)                      # [1, Q]
+    tri, upper = _tri(chunk)
+    cum = _tri_matvec(tri, dt * A)                     # [Q, 1] cumsum
     decay = jnp.exp(cum - cum.reshape(1, chunk))       # [Q, Q]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = ii >= jj
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # [Q, Q]
     W = jnp.where(tri, cb * decay, 0.0) * dt_row       # [Q, Q]
     ecum = jnp.exp(cum)                                # [Q, 1]
     Cs = Cm * ecum                                     # [Q, N]
-    cum_last = cum[chunk - 1]                          # [1]
-    eQ = jnp.exp(cum_last)[0]                          # scalar
-    w_last = jnp.exp(cum_last.reshape(1, 1) - cum) * dt           # [Q, 1]
+    cum_last = _scalar(cum[chunk - 1:, :])
+    eQ = jnp.exp(cum_last)
+    w_last = jnp.exp(cum_last - cum) * dt              # [Q, 1]
 
     # --- y_intra = W x ------------------------------------------------
     dW = jax.lax.dot_general(G, x, (((1,), (1,)), ((), ())))      # [Q, Q]
@@ -226,7 +249,7 @@ def _ssd_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, gy_ref,
     BH = jax.lax.dot_general(Bm, dS1, (((1,), (1,)), ((), ())))   # [Q, P]
     dx = (jax.lax.dot_general(W, G, (((0,), (0,)), ((), ())))     # W^T G
           + BH * w_last)
-    dx_ref[0, :, 0, :] = dx.astype(dx_ref.dtype)
+    dx_ref[0, 0] = dx.astype(dx_ref.dtype)
 
     # d(cb) = tri * dW * decay * dt_j  (mask AFTER multiply: above-diag
     # decay can be inf; 0 * inf = NaN)
@@ -237,8 +260,8 @@ def _ssd_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, gy_ref,
           + GS0 * ecum)
     dB = (jax.lax.dot_general(dcb, Cm, (((0,), (0,)), ((), ())))
           + xdS1 * w_last)
-    dc_ref[0, :, 0, :] = dC.astype(dc_ref.dtype)
-    db_ref[0, :, 0, :] = dB.astype(db_ref.dtype)
+    dc_ref[0, 0] = dC.astype(dc_ref.dtype)
+    db_ref[0, 0] = dB.astype(db_ref.dtype)
 
     # --- cum cotangent ------------------------------------------------
     TW = dW * W                                        # [Q, Q], tri via W
@@ -257,15 +280,14 @@ def _ssd_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, gy_ref,
     # --- dt cotangent -------------------------------------------------
     ddt = (jnp.sum(jnp.where(tri, dW * decay, 0.0) * cb,
                    axis=0).reshape(chunk, 1)           # W's dt_j factor
-           + dw * jnp.exp(cum_last.reshape(1, 1) - cum))  # w_last's dt
+           + dw * jnp.exp(cum_last - cum))             # w_last's dt
     # cumsum backward: da_i = sum_{i' >= i} dcum_{i'}
-    da = (jnp.sum(dcum, axis=0, keepdims=True)
-          - jnp.cumsum(dcum, axis=0) + dcum)
+    da = _tri_matvec(upper, dcum)
     ddt = ddt + da * A
-    ddt_ref[0] = ddt.astype(ddt_ref.dtype)
-    # dA partial for THIS chunk — one [1,1,1] block per grid cell
+    ddt_ref[0, 0] = ddt.reshape(1, chunk).astype(ddt_ref.dtype)
+    # dA partial for THIS chunk — one [1, 1] block per grid cell
     # (single-writer; the cross-chunk/batch sum happens outside)
-    da_ref[0, 0, 0] = jnp.sum(da * dt)
+    da_ref[0, 0, 0] = jnp.sum(da * dt, axis=0, keepdims=True)
 
     # --- state cotangent for the PRECEDING chunk ----------------------
     dstate_scratch[...] = (eQ * dS1
@@ -288,42 +310,43 @@ def ssd_bwd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     N = B.shape[-1]
     chunk = min(chunk, max(S, 8))
     pad = (-S) % chunk
-    if pad:
-        x, dt, B, C, gy = (_pad_seq(t, pad) for t in (x, dt, B, C, gy))
     S_p = S + pad
     nc = S_p // chunk
-    a2 = A.reshape(H, 1)
+    xt, dtt, Bt, Ct = _head_major(x, dt, B, C, pad)
+    gyt = _pad_seq(gy, pad).transpose(0, 2, 1, 3)
+    a3 = A.reshape(H, 1, 1)
 
-    seq_p = lambda i, h, c: (i, nc - 1 - c, h, 0)      # reversed chunks
-    seq_p3 = lambda i, h, c: (i, nc - 1 - c, h)
+    seq_p = lambda i, h, c: (i, h, nc - 1 - c, 0)      # reversed chunks
+    row_p = lambda i, h, c: (i, h, 0, nc - 1 - c)
     kernel = functools.partial(_ssd_bwd_kernel, chunk=chunk)
-    dx, ddt, dB, dC, dA3 = checked_pallas_call(
+    dx, ddt, dB, dC, dA5 = checked_pallas_call(
         "ssd_bwd", kernel,
         grid=(b, H, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), seq_p),
-            pl.BlockSpec((1, chunk, 1), seq_p3),
-            pl.BlockSpec((1, 1), lambda i, h, c: (h, 0)),
-            pl.BlockSpec((1, chunk, 1, N), seq_p),
-            pl.BlockSpec((1, chunk, 1, N), seq_p),
+            pl.BlockSpec((1, 1, chunk, P), seq_p),
+            pl.BlockSpec((1, 1, 1, chunk), row_p),
+            pl.BlockSpec((1, 1, 1), lambda i, h, c: (h, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, N), seq_p),
+            pl.BlockSpec((1, 1, chunk, N), seq_p),
             pl.BlockSpec((1, 1, 1, P, N),
                          lambda i, h, c: (i, h, nc - 1 - c, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, P), seq_p),
+            pl.BlockSpec((1, 1, chunk, P), seq_p),
             pl.BlockSpec((1, 1, P, N), lambda i, h, c: (i, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), seq_p),
-            pl.BlockSpec((1, chunk, 1), seq_p3),
-            pl.BlockSpec((1, chunk, 1, N), seq_p),
-            pl.BlockSpec((1, chunk, 1, N), seq_p),
-            pl.BlockSpec((1, 1, 1), lambda i, h, c: (i, h, nc - 1 - c)),
+            pl.BlockSpec((1, 1, chunk, P), seq_p),
+            pl.BlockSpec((1, 1, 1, chunk), row_p),
+            pl.BlockSpec((1, 1, chunk, N), seq_p),
+            pl.BlockSpec((1, 1, chunk, N), seq_p),
+            pl.BlockSpec((1, 1, 1, 1, 1),
+                         lambda i, h, c: (i, h, nc - 1 - c, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, S_p, H, P), x.dtype),
-            jax.ShapeDtypeStruct((b, S_p, H), dt.dtype),
-            jax.ShapeDtypeStruct((b, S_p, H, N), B.dtype),
-            jax.ShapeDtypeStruct((b, S_p, H, N), C.dtype),
-            jax.ShapeDtypeStruct((b, H, nc), jnp.float32),
+            jax.ShapeDtypeStruct((b, H, S_p, P), x.dtype),
+            jax.ShapeDtypeStruct((b, H, 1, S_p), dt.dtype),
+            jax.ShapeDtypeStruct((b, H, S_p, N), B.dtype),
+            jax.ShapeDtypeStruct((b, H, S_p, N), C.dtype),
+            jax.ShapeDtypeStruct((b, H, nc, 1, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((P, N), jnp.float32),           # dstate carry
@@ -331,6 +354,8 @@ def ssd_bwd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         interpret=interpret,
         sequential_axes=(2,),
         scratch_carry_axes=(2,),
-    )(x, dt, a2, B, C, cstates, gy, gstate)
-    dA = jnp.sum(dA3, axis=(0, 2)).astype(A.dtype)
-    return dx[:, :S], ddt[:, :S], dA, dB[:, :S], dC[:, :S]
+    )(xt, dtt, a3, Bt, Ct, cstates, gyt, gstate)
+    dA = jnp.sum(dA5, axis=(0, 2, 3, 4)).astype(A.dtype)
+    seq_major = lambda t: t[:, :, :S].transpose(0, 2, 1, 3)
+    return (seq_major(dx), ddt[:, :, 0, :S].transpose(0, 2, 1), dA,
+            seq_major(dB), seq_major(dC))

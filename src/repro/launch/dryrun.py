@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count on first backend initialization.  512 placeholder host devices
-# cover both the single-pod (16x16) and multi-pod (2x16x16) meshes.
-
 """Multi-pod dry-run: lower + compile EVERY assigned (arch x shape) cell
 on the production meshes, prove it fits, and extract roofline terms.
 
@@ -28,10 +22,14 @@ Usage:
   python -m repro.launch.dryrun --arch qwen3-1.7b --shape train_4k
   python -m repro.launch.dryrun --mesh multi --strategy fsdp
 Artifacts append to artifacts/dryrun.json (resumable; done cells skip).
+Run as a program, it compiles against 512 placeholder host devices,
+which cover both the single-pod (16x16) and multi-pod (2x16x16) meshes;
+importing the module sets nothing.
 """
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional
@@ -41,8 +39,7 @@ import jax
 from repro.configs import SHAPES, all_archs, cells_for, get_arch
 from repro.launch import specs as sp
 from repro.launch.hloparse import analyze
-from repro.launch.mesh import (cost_analysis_dict, data_axes,
-                               make_production_mesh, mesh_chips)
+from repro.launch.mesh import data_axes, make_production_mesh, mesh_chips
 from repro.optim import adamw
 from repro.runtime.sharding import ShardingStrategy
 from repro.runtime import spmd
@@ -103,7 +100,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     text = compiled.as_text()
     stats = analyze(text, default_group=mesh.shape[strategy.model_axis])
@@ -236,4 +233,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # before jax initializes its backend: the device count is fixed then
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

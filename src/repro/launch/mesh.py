@@ -6,43 +6,19 @@ touches jax device state.  Single pod: 16x16 = 256 chips over
 axis as an outer data-parallel dimension (pipeline-replica groups per
 pod; cross-pod traffic is the layer-bucket gradient sync, which rides
 DCN — see DESIGN.md §5).
-
-``make_mesh_compat``/``cost_analysis_dict`` absorb JAX API drift: the
-``axis_types=`` kwarg (jax.sharding.AxisType) and the dict-valued
-``Compiled.cost_analysis()`` only exist on newer JAX; on the installed
-floor we construct the mesh without axis types (Auto is the default
-behaviour there anyway) and unwrap the legacy one-element list.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Tuple
 
 import jax
-
-
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """jax.make_mesh with explicit Auto axis types when the installed
-    JAX has them (>= 0.5), plain construction otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` as a dict on every supported JAX
-    (older releases return a one-element list of dicts)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(multi_pod: bool) -> Tuple[str, ...]:

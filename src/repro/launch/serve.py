@@ -21,11 +21,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_arch, reduced
+from repro.configs import get_arch, sized
 from repro.core import build_profile
 from repro.core.engine import EngineConfig, OobleckEngine
 from repro.models import Model
 from repro.runtime.serve_exec import SamplingParams, ServeExecutor
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_serving_engine(arch, *, nodes, fault_tolerance: int = 1,
@@ -54,19 +55,21 @@ def main(argv=None) -> dict:
                     help="generated tokens per request")
     ap.add_argument("--requests", type=int, default=0,
                     help="request count (default: one per slot)")
-    ap.add_argument("--layers", type=int, default=2)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="block count: the reduced config's (default 2), "
+                         "or with --full a depth cut of the published one")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--nodes", type=int, default=6)
     ap.add_argument("--fail-at", type=int, default=-1,
                     help="inject a node failure after this many ticks")
-    ap.add_argument("--full", action="store_true")
+    ap.add_argument("--full", action="store_true",
+                    help="every published width (see --layers)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    arch = get_arch(args.arch)
-    if not args.full:
-        arch = reduced(arch, layers=args.layers)
+    arch = sized(get_arch(args.arch), full=args.full, layers=args.layers)
     model = Model(arch, dtype=jnp.float32, remat=False)
     # independent keys for params, data and sampling (a shared key would
     # correlate the prompts with the weights)

@@ -9,8 +9,9 @@ interface (runtime/executor.py): training steps are cached-program
 calls, reconfiguration swaps programs by cache lookup, and checkpoint
 hooks go through ``Executor.snapshot()``.
 
-Container-friendly: uses a reduced config by default (--full to use the
-exact assigned config — sized for the production mesh, not a CPU).
+Container-friendly: uses a reduced config by default.  ``--full`` keeps
+every published width; ``--full --layers N`` then cuts depth only (how
+chip_smoke.py fits GPT-3 Medium on one chip).
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch glm4-9b --nodes 5 --f 1 --steps 6 --kill-at 3
@@ -18,6 +19,7 @@ exact assigned config — sized for the production mesh, not a CPU).
 from __future__ import annotations
 
 import argparse
+import gc
 import time
 
 import jax
@@ -25,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt import CheckpointManager
-from repro.configs import get_arch, reduced
+from repro.configs import get_arch, sized
 from repro.core import EngineConfig, OobleckEngine, build_profile
 from repro.data import ByteCorpus, GlobalBatchDispenser, SyntheticLM
 
@@ -37,6 +39,7 @@ _TEXT = (b"Oobleck enables resilient distributed training of large models "
 from repro.models import Model
 from repro.optim import adamw
 from repro.runtime import HeteroTrainer
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def microbatches(batch, mb_size):
@@ -68,7 +71,7 @@ def run_multiproc(args) -> dict:
 
     nodes = [f"node{i}" for i in range(args.nodes)]
     spec = make_job_spec(
-        arch=args.arch, layers=args.layers, seq_len=args.seq_len,
+        arch=args.arch, layers=args.layers or 4, seq_len=args.seq_len,
         microbatch=args.microbatch, global_batch=args.global_batch,
         f=args.f, n0=args.n0, nodes=nodes, nodes_per_pod=args.pods,
         hosting=_multiproc_hosting(nodes, args.procs), procs=args.procs,
@@ -123,7 +126,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--global-batch", type=int, default=16)
     ap.add_argument("--microbatch", type=int, default=2)
     ap.add_argument("--seq-len", type=int, default=32)
-    ap.add_argument("--layers", type=int, default=4)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="block count: the reduced config's (default 4), "
+                         "or with --full a depth cut of the published one")
     ap.add_argument("--pods", type=int, default=8,
                     help="nodes per pod for the recovery data plane "
                          "(intra-pod copies ride ICI, cross-pod DCN)")
@@ -158,7 +163,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--ssd-impl", default="chunked",
                     choices=["chunked", "scan", "kernel", "auto"],
                     help="SSD path for Mamba2/hybrid stage layers")
-    ap.add_argument("--full", action="store_true")
+    ap.add_argument("--full", action="store_true",
+                    help="every published width (see --layers)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--procs", type=int, default=0,
                     help="run through the multi-process backend with N "
@@ -166,6 +172,7 @@ def main(argv=None) -> dict:
                          "--kill-at then SIGKILLs a worker and recovery "
                          "runs from heartbeat detection")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.procs > 0:
         return run_multiproc(args)
@@ -176,9 +183,8 @@ def main(argv=None) -> dict:
         print(f"[sync] --eager ignores --codec {args.codec}: the per-layer "
               f"reference path syncs uncompressed")
         args.codec = "none"
-    arch = get_arch(args.arch)
-    if not args.full:
-        arch = reduced(arch, layers=args.layers)
+    arch = sized(get_arch(args.arch), full=args.full, layers=args.layers,
+                 smoke_layers=4)
     model = Model(arch, dtype=jnp.float32, remat=False,
                   attn_impl=args.attn_impl, ssd_impl=args.ssd_impl,
                   scan_layers=False)
@@ -205,13 +211,15 @@ def main(argv=None) -> dict:
     trainer = HeteroTrainer(model, engine, params, opt_cfg,
                             mode="eager" if args.eager else "compiled",
                             codec=args.codec)
+    warm_s = 0.0
     if not args.eager and not args.no_warm:
         t0 = time.perf_counter()
         stats = trainer.warm_templates()
+        warm_s = time.perf_counter() - t0
         print(f"[warm] {stats['compiles']} programs compiled for "
-              f"{len(engine.templates)} templates in "
-              f"{time.perf_counter() - t0:.1f}s — any reconfiguration now "
-              f"swaps programs by lookup")
+              f"{len(engine.templates)} templates in {warm_s:.1f}s — any "
+              f"reconfiguration now swaps programs by lookup")
+    warm_compiles = trainer.cache.stats.compiles
     source = ByteCorpus(_TEXT * 50, seq_len=args.seq_len)
     disp = GlobalBatchDispenser(source)
     mgr = None
@@ -222,13 +230,14 @@ def main(argv=None) -> dict:
         engine.on_checkpoint = lambda: mgr.save(
             trainer.snapshot(disp.state(), args.seed), block=True)
 
-    losses = []
+    losses, step_s, first_batches, recover_s = [], [], None, None
     for step in range(args.steps):
         if step == args.kill_at:
             victim = engine.instances[0].nodes[-1]
             t0 = time.perf_counter()
             info = trainer.recover({victim})
-            wall = time.perf_counter() - t0
+            jax.block_until_ready([r.states for r in trainer.runs])
+            wall = recover_s = time.perf_counter() - t0
             if info["policy"] == "adapt":
                 bd = info["breakdown"]
                 print(f"[fail] killed {victim}: adapted schedule in "
@@ -251,12 +260,20 @@ def main(argv=None) -> dict:
             raise SystemExit("join-at requires the elastic example; see "
                              "examples/spot_trace_replay.py")
         batches = disp.next_step(engine.batch.minibatch_sizes())
-        out = trainer.step(
-            [microbatches(b, args.microbatch) for b in batches])
-        losses.append(float(out["loss"]))        # host sync at step edge
+        mbs = [microbatches(b, args.microbatch) for b in batches]
+        if first_batches is None:
+            first_batches = [mb for per in mbs for mb in per]
+        t0 = time.perf_counter()
+        out = trainer.step(mbs)
+        # the step ends when every replica's optimizer update has landed
+        jax.block_until_ready((out["loss"],
+                               [r.states for r in trainer.runs]))
+        step_s.append(time.perf_counter() - t0)
+        losses.append(float(out["loss"]))
         print(f"[step {step}] loss={losses[-1]:.4f} "
               f"pipelines={out['num_pipelines']} "
-              f"divergence={trainer.replica_divergence():.2e}")
+              f"divergence={trainer.replica_divergence():.2e} "
+              f"wall={step_s[-1]:.3f}s")
         if mgr and args.ckpt_every and (step + 1) % args.ckpt_every == 0:
             mgr.save(trainer.snapshot(disp.state(), args.seed))
     if mgr:
@@ -264,7 +281,16 @@ def main(argv=None) -> dict:
     assert losses[-1] < losses[0], "training must reduce the loss"
     print(f"[done] loss {losses[0]:.4f} -> {losses[-1]:.4f} "
           f"(cache: {trainer.cache.stats.as_dict()})")
-    return {"losses": losses}
+    result = {"losses": losses, "step_seconds": step_s,
+              "warm_seconds": warm_s, "recover_seconds": recover_s,
+              "warm_compiles": warm_compiles,
+              "compiles": trainer.cache.stats.compiles,
+              "divergence": trainer.replica_divergence(),
+              "first_batches": first_batches, "arch": arch}
+    # free the replicas' device state before the caller uses the device
+    del trainer, engine
+    gc.collect()
+    return result
 
 
 if __name__ == "__main__":

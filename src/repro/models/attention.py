@@ -8,11 +8,10 @@ Three prefill paths with identical semantics:
     flash-attention recurrence in pure XLA).  HBM traffic is O(S) instead
     of O(S^2); this path is also the kernel's numerical oracle.
   * ``kernel``  — the Pallas flash-attention kernel through kernels/ops.py
-    with its registered Pallas BACKWARD (custom_vjp), autotuned block
-    sizes, compiled wherever the one-shot lowering probe
-    (ops.kernel_lowers, DESIGN.md §13) finds a backend lowering for the
-    kernel structure, interpreted elsewhere.  This is the stage hot
-    path the per-template compiled programs run.
+    with its registered Pallas BACKWARD (custom_vjp) and autotuned block
+    sizes: compiled on an accelerator (a kernel that does not lower
+    raises, DESIGN.md §13), interpreted on the CPU.  This is the stage
+    hot path the per-template compiled programs run.
 
 ``fused=True`` additionally routes the QKV projection through
 ops.fused_qkv — ONE GEMM against the concatenated [d, (H+2KV)*hd]

@@ -48,17 +48,16 @@ class Model:
     remat_policy: str = "full"
     # "kernel" routes stage layers through the Pallas kernels in
     # kernels/ops.py (fwd AND bwd custom_vjp, autotuned blocks); "auto"
-    # resolves PER KERNEL via the one-shot lowering probe
-    # (ops.kernel_lowers, DESIGN.md §13): "kernel" wherever fwd AND bwd
-    # of that kernel lower compiled, the pure-XLA path otherwise.
+    # resolves to "kernel" on an accelerator (where ops.kernel_lowers
+    # raises if the kernel does not compile, DESIGN.md §13) and to the
+    # pure-XLA path on the CPU.
     attn_impl: str = "blocked"          # blocked | naive | kernel | auto
     ssd_impl: str = "chunked"           # chunked | scan | kernel | auto
     moe_impl: str = "dense"             # dense | grouped
     # "fused" routes the residual-add+RMSNorm block epilogue and the
     # QKV projection through ops.fused_add_rmsnorm / ops.fused_qkv
-    # (Pallas where the probe lowers them, XLA-level fusion otherwise);
-    # "none" keeps the op-per-line formulation.  "auto" == "fused": the
-    # routing layer already degrades gracefully per backend.
+    # (Pallas on an accelerator, XLA-level fusion on the CPU); "none"
+    # keeps the op-per-line formulation.  "auto" == "fused".
     fuse: str = "auto"                  # auto | fused | none
     constrain: Constrain = _identity_constrain
     # hook applied to a block's params at entry (FSDP gather-at-use)

@@ -36,11 +36,11 @@ import queue
 import socket
 import struct
 import threading
+import time
 import traceback
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import HeartbeatConfig, HeartbeatTracker
@@ -138,10 +138,10 @@ def pack_tree(tree: Any) -> Tuple[List[List], List[bytes]]:
 
 def unpack_tree(skeleton: Any, spec: Sequence[Sequence],
                 blobs: Sequence[bytes]) -> Any:
-    """Rebuild a pytree from ``pack_tree`` output.  ``skeleton`` is any
-    pytree with the same structure (avals or arrays); each leaf's shape
-    and dtype come from the wire spec and are cross-checked against the
-    skeleton's key paths."""
+    """Rebuild a pytree of HOST (numpy) arrays from ``pack_tree`` output.
+    ``skeleton`` is any pytree with the same structure (avals or
+    arrays); each leaf's shape and dtype come from the wire spec and are
+    cross-checked against the skeleton's key paths."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(skeleton)
     if len(flat) != len(blobs):
         raise ValueError(f"skeleton has {len(flat)} leaves, "
@@ -151,8 +151,7 @@ def unpack_tree(skeleton: Any, spec: Sequence[Sequence],
         if jax.tree_util.keystr(path) != key:
             raise ValueError(f"tree structure mismatch at {key!r} vs "
                              f"{jax.tree_util.keystr(path)!r}")
-        leaves.append(jnp.asarray(
-            np.frombuffer(raw, dtype=dtype).reshape(shape)))
+        leaves.append(np.frombuffer(raw, dtype=dtype).reshape(shape).copy())
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
@@ -233,13 +232,29 @@ class CoordinatorServer:
         self._closed = False
 
     # -- bootstrap -----------------------------------------------------
-    def accept_workers(self, timeout: float = 120.0) -> Dict[int, Header]:
+    def accept_workers(self, timeout: float = 120.0,
+                       exited: Optional[Callable[[], Optional[str]]] = None
+                       ) -> Dict[int, Header]:
         """Block until every expected worker has connected and said
         HELLO; returns rank -> hello header (which carries the worker's
-        data-server address)."""
-        self._listener.settimeout(timeout)
+        data-server address and its platform).  ``exited`` reports a
+        worker that died before connecting, which fails start-up at
+        once instead of at the timeout."""
+        deadline = time.monotonic() + timeout
         for _ in range(self.nprocs):
-            sock, _ = self._listener.accept()
+            while True:
+                self._listener.settimeout(
+                    max(0.0, min(1.0, deadline - time.monotonic())))
+                try:
+                    sock, _ = self._listener.accept()
+                    break
+                except socket.timeout:
+                    why = exited() if exited is not None else None
+                    if why:
+                        raise ConnectionError(f"start-up failed: {why}")
+                    if time.monotonic() >= deadline:
+                        raise
+            sock.settimeout(None)
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             header, _ = recv_msg(sock)
             if header.get("type") != "hello":

@@ -7,10 +7,10 @@ ExecutionEngine per node runs the compiled programs (§3).  This module
 is that split for real processes:
 
   * ``MultiHostExecutor`` — the coordinator.  Runs in the driver
-    process, owns a pure ``ConfigurationEngine`` (plans only, no device
-    state beyond a canonical parameter template used to decode
-    snapshots), a ``CoordinatorServer`` control channel, and the worker
-    subprocesses.  Implements the same ``Executor`` interface as the
+    process, owns a pure ``ConfigurationEngine`` (plans only, on shapes:
+    it never touches a device, which on an accelerator host belongs to a
+    worker), a ``CoordinatorServer`` control channel, and the worker
+    subprocesses, which inherit the platform (one per chip).  Implements the same ``Executor`` interface as the
     single-process ``HeteroTrainer`` — the conformance suite runs
     against both.
   * ``ShardTrainer`` — the per-process ExecutionEngine.  A
@@ -117,11 +117,13 @@ def make_job_spec(arch: str = "gpt3_medium", layers: int = 4,
     }
 
 
-def build_setup(spec: Dict):
+def build_setup(spec: Dict, abstract: bool = False):
     """Deterministically rebuild (model, params, profile, opt_cfg,
     engine) from a job spec — run by the coordinator AND by every
     worker, so each process's ConfigurationEngine replica starts from
-    the identical plan."""
+    the identical plan.  ``abstract`` returns the params as shapes
+    (``jax.eval_shape``): the coordinator plans without touching a
+    device, which on a TPU host belongs to a worker."""
     from repro.configs import get_arch, reduced
     from repro.core import build_profile
     from repro.models import Model
@@ -129,7 +131,8 @@ def build_setup(spec: Dict):
     arch = reduced(get_arch(spec["arch"]), layers=spec["layers"])
     model = Model(arch, dtype=jnp.float32, remat=False, attn_impl="naive",
                   scan_layers=False)
-    params = model.init(jax.random.PRNGKey(spec["seed"]))
+    init = lambda: model.init(jax.random.PRNGKey(spec["seed"]))
+    params = jax.eval_shape(init) if abstract else init()
     profile = build_profile(arch, microbatch=spec["microbatch"],
                             seq_len=spec["seq_len"])
     opt_cfg = adamw.AdamWConfig(**spec["opt"])
@@ -329,7 +332,8 @@ class ShardTrainer(HeteroTrainer):
             reply, blobs = data_call(
                 data_addrs[src_rank],
                 {"type": "get_state", "node": src, "layer": l})
-            st = unpack_tree(self._state_skeleton(l), reply["spec"], blobs)
+            st = jax.tree.map(jnp.asarray, unpack_tree(
+                self._state_skeleton(l), reply["spec"], blobs))
             fetched["bytes"] += sum(len(b) for b in blobs)
             fetched["fetches"] += 1
             fetched["seconds"] += time.perf_counter() - t0
@@ -385,7 +389,9 @@ class Worker:
         self.server = DataServer(self._serve_data)
         self.channel = WorkerChannel(
             coordinator, rank,
-            hello={"data_addr": list(self.server.addr), "pid": os.getpid()},
+            hello={"data_addr": list(self.server.addr), "pid": os.getpid(),
+                   "platform": jax.default_backend(),
+                   "local_devices": jax.local_device_count()},
             beat_interval=beat_interval)
 
     # -- data plane ----------------------------------------------------
@@ -527,6 +533,23 @@ def worker_main(coordinator: str, rank: int) -> None:
     Worker((host, int(port)), rank).run()
 
 
+def _check_worker_devices(hellos: Dict[int, Dict]) -> None:
+    """One process per chip: workers must share a platform (a worker
+    that could not open a chip held by another would fall back to the
+    CPU), and an accelerator host cannot take more workers than chips."""
+    platforms = {h["platform"] for h in hellos.values()}
+    if len(platforms) > 1:
+        raise RuntimeError(
+            f"workers started on different platforms {sorted(platforms)}: "
+            f"an accelerator chip belongs to one process at a time")
+    platform = platforms.pop()
+    chips = min(h["local_devices"] for h in hellos.values())
+    if platform != "cpu" and len(hellos) > chips:
+        raise RuntimeError(
+            f"{len(hellos)} workers on {platform} but {chips} local "
+            f"chip(s): launch at most one worker per chip")
+
+
 # ----------------------------------------------------------------------
 # The coordinator-side Executor
 # ----------------------------------------------------------------------
@@ -552,13 +575,15 @@ class MultiHostExecutor(Executor):
         self.server = CoordinatorServer(len(ranks), heartbeat)
         self.procs: Dict[int, subprocess.Popen] = {}
         self._spawn_workers(ranks, python)
-        hellos = self.server.accept_workers(timeout=rpc_timeout)
+        hellos = self.server.accept_workers(timeout=rpc_timeout,
+                                            exited=self._exited_worker)
+        _check_worker_devices(hellos)
         self.data_addrs = {r: list(h["data_addr"])
                            for r, h in hellos.items()}
-        # the coordinator's CONFIGURATION side: plans only.  The params
-        # template is kept host-side purely to decode snapshot pytrees.
+        # the coordinator's CONFIGURATION side: plans only, on shapes —
+        # the params template exists purely to decode snapshot pytrees
         (self.model, self._template_params, self.profile,
-         self.opt_cfg, self.engine) = build_setup(self.spec)
+         self.opt_cfg, self.engine) = build_setup(self.spec, abstract=True)
         replies = self.server.broadcast_call(
             {"type": "job", "spec": self.spec}, timeout=rpc_timeout)
         fp0 = self.engine.plan_fingerprint()
@@ -586,11 +611,17 @@ class MultiHostExecutor(Executor):
             env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
             env["REPRO_PROC_COUNT"] = str(len(ranks))
             env["REPRO_PROC_INDEX"] = str(r)
-            env.setdefault("JAX_PLATFORMS", "cpu")
             cmd = [python or sys.executable,
                    "-m", "repro.runtime.multihost_worker",
                    "--coordinator", f"{host}:{port}", "--rank", str(r)]
             self.procs[r] = subprocess.Popen(cmd, env=env)
+
+    def _exited_worker(self) -> Optional[str]:
+        """Why start-up cannot complete, if a worker already exited."""
+        for r, p in sorted(self.procs.items()):
+            if p.poll() is not None:
+                return f"worker rank {r} exited with code {p.returncode}"
+        return None
 
     def kill_worker(self, rank: int) -> None:
         """SIGKILL a worker process — the failure-injection primitive of
@@ -687,10 +718,9 @@ class MultiHostExecutor(Executor):
         if not replies:
             raise WorkerLost(list(by_rank), "no worker survived commit")
         gn_bytes = next(iter(sorted(replies.items())))[1][1][0]
-        grad_norm = jnp.asarray(
-            np.frombuffer(gn_bytes, np.float32).reshape(()))
+        grad_norm = np.frombuffer(gn_bytes, np.float32).reshape(())
         weights = [len(b) for b in batches]
-        scalars = [jnp.asarray(np.frombuffer(nll[i], np.float32).reshape(()))
+        scalars = [np.frombuffer(nll[i], np.float32).reshape(())
                    for i in order]
         # the EXACT single-process expression, replica order preserved
         loss = sum(scalars) / float(sum(weights))
@@ -827,7 +857,7 @@ class MultiHostExecutor(Executor):
                         blobs[n:2 * n])
         v = unpack_tree(self._template_params, h["spec_v"],
                         blobs[2 * n:3 * n])
-        opt = adamw.AdamWState(jnp.asarray(h["step"], jnp.int32), m, v)
+        opt = adamw.AdamWState(np.asarray(h["step"], np.int32), m, v)
         return TrainState(step=h["step"], params=params, opt_state=opt,
                           data_state=data_state or {}, rng_seed=rng_seed)
 

@@ -131,6 +131,38 @@ def make_stage_fn(model: Model, kinds: Sequence[str]) -> Callable:
     return fn
 
 
+def make_grads_fn(model: Model, stage_kinds: Sequence[Sequence[str]]
+                  ) -> Callable:
+    """One pipeline's step: fn(stage_params, tokens [M, b, s], labels,
+    *fe) -> (per-layer grad means, per-microbatch NLL [M]).  A scan over
+    microbatches with in-program gradient accumulation."""
+    fns = [make_stage_fn(model, k) for k in stage_kinds]
+
+    def loss_of(stage_params, tok, lab, fe):
+        carry = (tok, jnp.zeros((), jnp.float32))
+        for fn, sp in zip(fns, stage_params):
+            carry = fn(sp, carry, lab, fe)
+        loss, nll = carry
+        return loss, nll
+
+    def grads_fn(stage_params, tokens, labels, *fe_args):
+        def body(gsum, xs):
+            tok, lab = xs[0], xs[1]
+            fe = xs[2] if len(xs) > 2 else None
+            (_, nll), g = jax.value_and_grad(
+                loss_of, has_aux=True)(stage_params, tok, lab, fe)
+            return jax.tree.map(jnp.add, gsum, g), nll
+
+        zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype),
+                             stage_params)
+        xs = (tokens, labels) + tuple(fe_args)
+        gsum, nlls = jax.lax.scan(body, zeros, xs)
+        gsum = jax.tree.map(lambda g: g / tokens.shape[0], gsum)
+        return gsum, nlls
+
+    return grads_fn
+
+
 # ----------------------------------------------------------------------
 # One bound pipeline
 # ----------------------------------------------------------------------
@@ -282,35 +314,11 @@ class HeteroTrainer(Executor):
 
         def build() -> Callable:
             kinds = [[self._kind[l] for l in range(u, v)] for (u, v) in sig]
-            fns = [make_stage_fn(self.model, k) for k in kinds]
-            M = tok_aval.shape[0]
-
-            def loss_of(stage_params, tok, lab, fe):
-                carry = (tok, jnp.zeros((), jnp.float32))
-                for fn, sp in zip(fns, stage_params):
-                    carry = fn(sp, carry, lab, fe)
-                loss, nll = carry
-                return loss, nll
-
-            def grads_fn(stage_params, tokens, labels, *fe_args):
-                def body(gsum, xs):
-                    tok, lab = xs[0], xs[1]
-                    fe = xs[2] if len(xs) > 2 else None
-                    (_, nll), g = jax.value_and_grad(
-                        loss_of, has_aux=True)(stage_params, tok, lab, fe)
-                    return jax.tree.map(jnp.add, gsum, g), nll
-
-                zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype),
-                                     stage_params)
-                xs = (tokens, labels) + tuple(fe_args)
-                gsum, nlls = jax.lax.scan(body, zeros, xs)
-                gsum = jax.tree.map(lambda g: g / M, gsum)
-                return gsum, nlls
-
             avals = (self._stage_avals(sig), tok_aval, lab_aval)
             if fe_aval is not None:
                 avals = avals + (fe_aval,)
-            return jax.jit(grads_fn).lower(*avals).compile()
+            return jax.jit(make_grads_fn(self.model, kinds)).lower(
+                *avals).compile()
 
         return self.cache.get_or_build(key, build)
 

@@ -34,7 +34,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models import Model
 from repro.optim import adamw
@@ -66,8 +65,11 @@ def pipeline_forward(model: Model, params: Dict, x_mb: jax.Array,
         local = jax.tree.map(lambda t: t[0], stage_blocks)
         idx = jax.lax.axis_index(stage_axis)
         b, s, d = xs.shape[1:]
-        buf = jnp.zeros((b, s, d), xs.dtype)          # activation register
-        outs = jnp.zeros_like(xs)
+        # per-stage state: declared varying over the stage axis, so both
+        # branches of the completion cond below agree on their types
+        buf = jax.lax.pcast(jnp.zeros((b, s, d), xs.dtype), stage_axis,
+                            to="varying")             # activation register
+        outs = jax.lax.pcast(jnp.zeros_like(xs), stage_axis, to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -90,11 +92,11 @@ def pipeline_forward(model: Model, params: Dict, x_mb: jax.Array,
         # every stage holds its own `outs`; only the last stage's is real
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_program, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(stage_axis),
-        check_rep=False)
+        check_vma=True)
     stacked = fn(blocks, x_mb)          # [S*M, b, s, d] stage-major
     return stacked.reshape(S, M, *x_mb.shape[1:])[-1]
 

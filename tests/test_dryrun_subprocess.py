@@ -24,8 +24,8 @@ SCRIPT = textwrap.dedent("""
     from repro.launch.hloparse import analyze
     from repro.optim import adamw
 
-    from repro.launch.mesh import cost_analysis_dict, make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     arch = reduced(get_arch(sys.argv[1]), layers=2, d_model=64, vocab=512)
     shape = ShapeConfig("tiny", seq_len=64, global_batch=8, kind=sys.argv[2])
     strategy = ShardingStrategy(strategy="fsdp", data_axes=("data",))
@@ -51,7 +51,7 @@ SCRIPT = textwrap.dedent("""
         "flops": st.dot_flops,
         "coll": st.collective_bytes,
         "temps": ma.temp_size_in_bytes,
-        "xla_flops": cost_analysis_dict(compiled).get("flops", 0.0),
+        "xla_flops": compiled.cost_analysis().get("flops", 0.0),
     }))
 """)
 

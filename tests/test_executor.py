@@ -4,9 +4,9 @@ The contract under test:
 
   1. PARITY — the cached per-(template, microbatch-count) step program
      computes the SAME training step as the eager 1F1B reference:
-     per-microbatch NLL bit-identical, per-layer gradients equal to
-     float32 ULP noise (XLA fuses the compiled backward, so last-bit
-     rounding can differ from the op-by-op eager chain), and the
+     per-microbatch NLL and per-layer gradients equal to float32 ULP
+     noise (XLA fuses the compiled program, so last-bit rounding can
+     differ from the op-by-op eager chain), and the
      trajectory stays locked through a failure -> recover -> step cycle.
   2. ZERO RECOMPILATION — after warm_templates(), a failure, recovery
      and the first post-recovery step trigger no program-cache compiles
@@ -35,6 +35,10 @@ from repro.runtime import (Executor, ExecutorUnsupported, HeteroTrainer,
 
 RNG = jax.random.PRNGKey(11)
 GB, MB, SEQ = 16, 2, 16
+# XLA fuses the compiled forward's log-sum-exp differently from the
+# op-by-op eager chain, so the NLL's last bits may round differently
+# (2 ULP observed); 4 fp32 epsilons relative allows a few ULP and no more
+NLL_RTOL = 4 * float(np.finfo(np.float32).eps)
 
 
 def make_setup(n_nodes=5, f=1, arch_name="gpt3_medium", layers=4):
@@ -97,11 +101,12 @@ def test_compiled_matches_eager_reference():
         pbc = [microbatches(b, MB) for b in bc]
         pbe = [microbatches(b, MB) for b in be]
 
-        # per-pipeline: NLL arrays bit-identical, grads ULP-equal
+        # per-pipeline: NLL and grads equal to fp32 ULP noise
         for rc, re_, mc, me in zip(tc.runs, te.runs, pbc, pbe):
             gc, nc = tc._run_pipeline(rc, mc)
             ge, ne = te._run_pipeline(re_, me)
-            np.testing.assert_array_equal(np.asarray(nc), np.asarray(ne))
+            np.testing.assert_allclose(np.asarray(nc), np.asarray(ne),
+                                       rtol=NLL_RTOL, atol=0)
             assert sorted(gc) == sorted(ge)
             for l in gc:
                 tree_allclose_ulp(gc[l], ge[l])
@@ -109,8 +114,9 @@ def test_compiled_matches_eager_reference():
         oc = tc.train_step(pbc)
         oe = te.train_step(pbe)
         if step == 0:
-            # identical params -> bit-identical NLL means
-            assert float(oc["loss"]) == float(oe["loss"])
+            # identical params -> NLL means equal to a few ULP
+            np.testing.assert_allclose(float(oc["loss"]), float(oe["loss"]),
+                                       rtol=NLL_RTOL, atol=0)
         else:
             # params have drifted by grad ULP noise * Adam by now
             assert abs(float(oc["loss"]) - float(oe["loss"])) < 1e-4

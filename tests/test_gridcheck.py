@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import gridcheck, ops
 from repro.kernels.gridcheck import (CallRecord, GridWriteError, REGISTRY,
@@ -88,7 +89,8 @@ def test_check_accepts_innermost_sequential_carry():
 
 def test_mosaic_semantics_derivation():
     params = gridcheck._mosaic_params((2, 3, 4), sequential_axes=(2,))
-    assert params["mosaic"]["dimension_semantics"] == (
+    assert isinstance(params, pltpu.CompilerParams)
+    assert tuple(params.dimension_semantics) == (
         "parallel", "parallel", "arbitrary")
 
 

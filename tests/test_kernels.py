@@ -361,36 +361,47 @@ def test_model_attention_kernel_path_matches_blocked():
         np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3)
 
 
-def test_model_auto_impl_resolves_for_backend():
+def test_model_auto_impl_resolves_for_backend(monkeypatch):
+    """"auto" is the XLA path on the CPU and the kernel on an
+    accelerator — where a kernel that does not lower raises instead of
+    falling back."""
     from repro.configs import get_arch, reduced
     from repro.models import Model
     arch = reduced(get_arch("gpt3_medium"), layers=2)
     m = Model(arch, attn_impl="auto", ssd_impl="auto")
-    if ops.interpret_mode():
-        assert m.attn_impl == "blocked" and m.ssd_impl == "chunked"
-    else:
-        assert m.attn_impl == "kernel" and m.ssd_impl == "kernel"
+    assert (m.attn_impl, m.ssd_impl) == ("blocked", "chunked")
+    ops._reset_lowering_cache()
+    try:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        for kind in ops.KERNEL_KINDS:
+            monkeypatch.setitem(ops._PROBES, kind, lambda: None)
+        m = Model(arch, attn_impl="auto", ssd_impl="auto")
+        assert (m.attn_impl, m.ssd_impl) == ("kernel", "kernel")
+        ops._reset_lowering_cache()
+
+        def refuse():
+            raise ValueError("block shape not tiled")
+
+        monkeypatch.setitem(ops._PROBES, "flash_bwd", refuse)
+        with pytest.raises(ops.KernelLoweringError,
+                           match="block shape not tiled"):
+            Model(arch, attn_impl="auto")
+    finally:
+        ops._reset_lowering_cache()
 
 
 def test_backend_signature_gating():
-    """Lowering is resolved PER KERNEL, not per platform: the
-    single-writer restructure lowers everywhere a Pallas backend
-    exists, while the SSD kernels keep a sequential-grid VMEM carry
-    that only Mosaic serializes — so TPU lowers everything, GPU lowers
-    flash + the fused epilogues but interprets SSD, and CPU (no
-    compiled Pallas at all) interprets everything.  The signature that
-    program caches key on carries the whole per-kind plan."""
+    """Lowering is decided by the backend: CPU (no compiled Pallas)
+    interprets every kind, TPU compiles every kind, and a backend with
+    no maintained lowering is an error rather than a silent fallback.
+    The signature that program caches key on carries the whole per-kind
+    plan."""
     for kind in ops.KERNEL_KINDS:
         assert ops.kernel_lowers(kind, "tpu"), kind
-    assert not ops.interpret_mode("tpu")
-    for backend in ("gpu", "cuda", "rocm"):
-        for kind in ("flash_fwd", "flash_bwd", "fused_norm", "fused_qkv"):
-            assert ops.kernel_lowers(kind, backend), (backend, kind)
-        for kind in ("ssd_fwd", "ssd_bwd"):
-            assert not ops.kernel_lowers(kind, backend), (backend, kind)
-        assert ops.interpret_mode(backend), backend   # any kind interprets
-    for kind in ops.KERNEL_KINDS:
         assert not ops.kernel_lowers(kind, "cpu"), kind
+    for backend in ("gpu", "cuda", "rocm"):
+        with pytest.raises(ops.KernelLoweringError, match=backend):
+            ops.kernel_lowers("flash_fwd", backend)
     sig = ops.backend_signature()
     backend = jax.default_backend()
     # (backend, process topology, per-kind plan): the topology leg keeps
@@ -404,26 +415,29 @@ def test_backend_signature_gating():
 
 
 def test_lowering_probe_runs_on_live_backend_and_caches(monkeypatch):
-    """On the LIVE backend the verdict comes from a one-shot try-compile
-    of the kernel structure, cached per (kind, backend) — not from the
-    static capability table."""
+    """On a live accelerator the verdict comes from a one-shot
+    try-compile of the kernel structure, cached per (kind, backend); a
+    failing compile raises with the compiler's message.  The CPU never
+    probes: it has no compiled Pallas."""
     ops._reset_lowering_cache()
     try:
         calls = []
-        orig = ops._PROBES["flash_fwd"]
-
-        def spy():
-            calls.append(1)
-            return orig()
-
-        monkeypatch.setitem(ops._PROBES, "flash_fwd", spy)
-        first = ops.kernel_lowers("flash_fwd")
-        second = ops.kernel_lowers("flash_fwd")
-        assert first == second
+        monkeypatch.setitem(ops._PROBES, "flash_fwd",
+                            lambda: calls.append(1))
+        assert ops.kernel_lowers("flash_fwd", "cpu") is False
+        assert not calls
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert ops.kernel_lowers("flash_fwd") is True
+        assert ops.kernel_lowers("flash_fwd") is True
         assert len(calls) == 1                      # one-shot, then cached
-        # CPU's Pallas is interpret-only: the probe must discover that
-        if jax.default_backend() == "cpu":
-            assert first is False
+
+        def refuse():
+            raise ValueError("Mosaic failed to compile")
+
+        monkeypatch.setitem(ops._PROBES, "ssd_bwd", refuse)
+        with pytest.raises(ops.KernelLoweringError,
+                           match="'ssd_bwd'.*Mosaic failed to compile"):
+            ops.kernel_lowers("ssd_bwd")
     finally:
         ops._reset_lowering_cache()
 
@@ -486,17 +500,35 @@ def test_flash_config_routes_ragged_seq_via_ragged_bucket(monkeypatch):
 
 
 def test_offline_heuristic_is_per_kernel_capability():
-    """GPU lowers flash/fused but interprets SSD: the offline defaults
-    must follow the per-kind probe, not a platform aggregate."""
+    """The offline defaults follow what the backend runs: compiled
+    kernels on TPU (MXU-aligned 128), the interpreter on the CPU."""
     c = autotune.AutotuneCache("/nonexistent/never-loaded.json")
-    assert c.get("flash", "gpu", jnp.float32, (2048, 64)) == {
+    assert c.get("flash", "tpu", jnp.float32, (2048, 64)) == {
         "block_q": 128, "block_k": 128}           # compiled heuristic
-    assert c.get("fused", "gpu", jnp.float32,
+    assert c.get("fused", "tpu", jnp.float32,
                  (2048, 768))["block_rows"] == 128
-    # seq 64: compiled heuristic would say 128, interpreter caps at the
-    # bucket — SSD on gpu must take the interpreter branch
-    assert c.get("ssd", "gpu", jnp.float32, (64, 64, 32)) == {"chunk": 64}
+    # seq 64: compiled heuristic says 128, interpreter caps at the bucket
+    assert c.get("ssd", "cpu", jnp.float32, (64, 64, 32)) == {"chunk": 64}
     assert c.get("ssd", "tpu", jnp.float32, (64, 64, 32)) == {"chunk": 128}
+
+
+def test_accelerator_blocks_ignore_measured_entries(tmp_path, monkeypatch):
+    """A chip run's blocks come from the checkout alone: measured
+    entries and REPRO_AUTOTUNE tuning steer only the CPU."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
+    cache = autotune.AutotuneCache(str(tmp_path / "m.json"))
+    for backend in ("cpu", "tpu"):
+        cache.put("flash", backend, jnp.float32, ("2048", 64),
+                  {"block_q": 256, "block_k": 256})
+    monkeypatch.setattr(autotune, "_CACHE", cache)
+    monkeypatch.setattr(autotune, "tune_flash", lambda *a, **k: 1 / 0)
+    assert autotune.flash_config("cpu", jnp.float32, 2048, 64) == {
+        "block_q": 256, "block_k": 256}
+    assert autotune.flash_config("tpu", jnp.float32, 2048, 64) == {
+        "block_q": 128, "block_k": 128}
+    # nothing is read from or written to a file the run did not name
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    assert autotune.AutotuneCache().path is None
 
 
 def test_packaged_offline_table_consulted(monkeypatch):

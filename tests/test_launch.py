@@ -2,9 +2,11 @@
 
 NOTE: these tests run with the default 1-device CPU backend — the
 512-device dry-run runs in its own process (launch/dryrun.py sets
-XLA_FLAGS before importing jax).  A small-device-count end-to-end dry-run
+XLA_FLAGS only when run as a program).  A small-device-count end-to-end dry-run
 happens in test_dryrun_subprocess.py.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -93,8 +95,7 @@ def test_hloparse_real_program():
     x = jax.ShapeDtypeStruct((16, 64), jnp.float32)
     c = jax.jit(f).lower(w, x).compile()
     st = analyze(c.as_text())
-    from repro.launch.mesh import cost_analysis_dict
-    xla = cost_analysis_dict(c).get("flops", 0)
+    xla = c.cost_analysis().get("flops", 0)
     assert st.dot_flops == pytest.approx(2 * 16 * 64 * 32, rel=0.01)
     assert st.dot_flops <= xla * 1.05 + 1e5
 
@@ -153,3 +154,33 @@ def test_model_flops_definitions():
     assert tr == pytest.approx(6 * arch.active_params() * 4096 * 256)
     de = model_flops(arch, SHAPES["decode_32k"])
     assert de == pytest.approx(2 * arch.active_params() * 128)
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """The launchers' compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says (and nothing else is set), else at <checkout>/.jax_cache — a
+    fixed path, since the path is part of what lets a later run hit."""
+    import os
+    from repro.utils import compile_cache
+    set_to = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: set_to.__setitem__(k, v))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert not set_to
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+    assert set_to == {"jax_compilation_cache_dir": path}
+
+
+def test_full_layers_cuts_depth_only():
+    from repro.configs import reduced, sized
+    pub = get_arch("gpt3_medium")
+    cut = sized(pub, full=True, layers=8)
+    assert cut.num_layers == 8
+    assert cut == dataclasses.replace(pub, num_layers=8)   # every width kept
+    assert sized(pub, full=True) == pub
+    assert sized(pub, full=False, layers=3) == reduced(pub, layers=3)
+    assert sized(pub, full=False, smoke_layers=4).num_layers == 4

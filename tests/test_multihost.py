@@ -130,6 +130,47 @@ def test_replan_fingerprint_is_hash_seed_independent():
     assert len(fps) == 1, fps
 
 
+def test_coordinator_plans_without_touching_a_device():
+    """On an accelerator host every chip belongs to a worker: the
+    coordinator's setup plans on shapes and never initializes a JAX
+    backend."""
+    import json
+    import subprocess
+    import sys
+
+    import repro
+    src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+    prog = (
+        "import json, sys\n"
+        "from jax._src import xla_bridge\n"
+        "from repro.runtime.multihost import build_setup\n"
+        "*_, engine = build_setup(json.loads(sys.argv[1]), abstract=True)\n"
+        "print(xla_bridge.backends_are_initialized(),"
+        " engine.plan_fingerprint())\n")
+    env = dict(os.environ, PYTHONPATH=src + os.pathsep + os.environ.get(
+        "PYTHONPATH", ""))
+    out = subprocess.run(
+        [sys.executable, "-c", prog, json.dumps(_spec(HOSTING, 3))],
+        env=env, capture_output=True, text=True, timeout=TIMEOUT)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split()[0] == "False", out.stdout
+
+
+def test_worker_device_check_one_process_per_chip():
+    from repro.runtime.multihost import _check_worker_devices
+    # CPU workers share the host's cores freely
+    _check_worker_devices({r: {"platform": "cpu", "local_devices": 1}
+                           for r in range(3)})
+    _check_worker_devices({0: {"platform": "tpu", "local_devices": 4}})
+    # a worker that could not open a held chip fell back to the CPU
+    with pytest.raises(RuntimeError, match="different platforms"):
+        _check_worker_devices({0: {"platform": "tpu", "local_devices": 1},
+                               1: {"platform": "cpu", "local_devices": 1}})
+    with pytest.raises(RuntimeError, match="one worker per chip"):
+        _check_worker_devices({r: {"platform": "tpu", "local_devices": 1}
+                               for r in range(2)})
+
+
 def test_sigkill_lifecycle_parity_zero_compiles(tmp_path):
     arch, ref = _reference()
     ref.warm_templates()

@@ -18,8 +18,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models import Model
     from repro.runtime.spmd_pipeline import pipeline_logits
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     arch = reduced(get_arch("gpt3_medium"), layers=8)   # 8 blocks / 4 stages
     model = Model(arch, dtype=jnp.float32, remat=False, attn_impl="naive")
     params = model.init(jax.random.PRNGKey(0))
@@ -53,14 +53,14 @@ TRAIN_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import get_arch, reduced
-    from repro.launch.mesh import make_mesh_compat
     from repro.models import Model
     from repro.models.layers import cross_entropy
     from repro.optim import adamw
     from repro.runtime.spmd_pipeline import (make_pipeline_train_step,
                                              pipeline_loss)
 
-    mesh = make_mesh_compat((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     arch = reduced(get_arch("gpt3_medium"), layers=8)
     model = Model(arch, dtype=jnp.float32, remat=False, attn_impl="naive")
     params = model.init(jax.random.PRNGKey(0))
@@ -91,11 +91,13 @@ TRAIN_SCRIPT = textwrap.dedent("""
         opt = adamw.init(params)
         p_ref, o_ref, _ = adamw.apply(opt_cfg, params, gr, opt)
         p2, o2, stats = step(params, opt, tokens, labels)
-        perr = max(float(jnp.max(jnp.abs(a - b)))
-                   for a, b in zip(jax.tree.leaves(p2),
-                                   jax.tree.leaves(p_ref)))
-    print(json.dumps({"gerr": gerr, "perr": perr,
-                      "loss": float(stats["loss"])}))
+        diffs = [np.abs(np.asarray(a) - np.asarray(b)).ravel()
+                 for a, b in zip(jax.tree.leaves(p2),
+                                 jax.tree.leaves(p_ref))]
+        diffs = np.concatenate(diffs)
+    print(json.dumps({"gerr": gerr, "perr": float(diffs.max()),
+                      "pfrac": float((diffs > opt_cfg.lr / 10).mean()),
+                      "lr": opt_cfg.lr, "loss": float(stats["loss"])}))
 """)
 
 
@@ -110,5 +112,10 @@ def test_shard_map_pipeline_train_step_matches_reference():
     assert out.returncode == 0, out.stderr[-2000:]
     r = json.loads(out.stdout.strip().splitlines()[-1])
     assert r["gerr"] < 1e-5, r
-    assert r["perr"] < 1e-5, r
+    # Adam's first step moves each element by lr * g / (|g| + eps), so
+    # grad ULP noise on an element near zero can move it by up to 2*lr
+    # whatever the noise's size; a systematic fault (wrong stage, missed
+    # microbatch) would move most elements instead of a few
+    assert r["perr"] <= 2 * r["lr"], r
+    assert r["pfrac"] < 1e-3, r
     assert 0 < r["loss"] < 20
