@@ -36,7 +36,6 @@ programs by cache lookup.  The heterogeneous JAX trainer
 from __future__ import annotations
 
 import dataclasses
-import time as _time
 from typing import Callable, Dict, List, Optional, Sequence, Set
 
 from repro.core import adapt as cm_adapt
@@ -53,6 +52,7 @@ from repro.core import sync as cm_sync
 from repro.core.sync import SyncBucket, build_sync_plan
 from repro.core.templates import (NodeSpec, PipelineTemplate,
                                   generate_node_spec)
+from repro.utils.spans import span
 
 
 @dataclasses.dataclass
@@ -128,23 +128,24 @@ class ConfigurationEngine:
         # runs just observe it as a counter.
         self.epoch = 0
 
-        t0 = _time.perf_counter()
-        n0 = (config.n0_override if config.n0_override is not None
-              else profile.min_nodes(config.gpus_per_node))
-        self.spec: NodeSpec = generate_node_spec(
-            N=len(nodes), f=config.fault_tolerance, n0=n0,
-            max_size=profile.num_layers)
-        planner = PipelinePlanner(profile, config.gpus_per_node,
-                                  mode=config.planner_mode,
-                                  max_stages=config.max_stages)
-        self.templates: Dict[int, PipelineTemplate] = planner.plan_all(
-            self.spec.sizes)
-        self.planner = planner
-        self.reconf = Reconfigurator(self.templates, self.spec, profile,
-                                     config.global_batch, config.microbatch)
-        plan = choose_plan(self.templates, self.spec, len(nodes),
-                           config.global_batch, config.microbatch)
-        self.metrics.planning_seconds = _time.perf_counter() - t0
+        with span("oobleck.plan.bootstrap") as sp:
+            n0 = (config.n0_override if config.n0_override is not None
+                  else profile.min_nodes(config.gpus_per_node))
+            self.spec: NodeSpec = generate_node_spec(
+                N=len(nodes), f=config.fault_tolerance, n0=n0,
+                max_size=profile.num_layers)
+            planner = PipelinePlanner(profile, config.gpus_per_node,
+                                      mode=config.planner_mode,
+                                      max_stages=config.max_stages)
+            self.templates: Dict[int, PipelineTemplate] = planner.plan_all(
+                self.spec.sizes)
+            self.planner = planner
+            self.reconf = Reconfigurator(self.templates, self.spec, profile,
+                                         config.global_batch,
+                                         config.microbatch)
+            plan = choose_plan(self.templates, self.spec, len(nodes),
+                               config.global_batch, config.microbatch)
+        self.metrics.planning_seconds = sp.seconds
 
         self.instances: List[PipelineInstance] = []
         cursor = 0
@@ -335,12 +336,11 @@ class ConfigurationEngine:
         damaged replicas' healthy nodes park as hot spares.  Raises
         ``AdaptationError`` when infeasible (every replica damaged, or
         the batch cannot redistribute over the survivors)."""
-        t0 = _time.perf_counter()
-        plan = cm_adapt.plan_adaptation(
-            self.instances, self.batch.num_microbatches, sorted(dead),
-            self.config.global_batch, self.config.microbatch)
-        return dataclasses.replace(
-            plan, replan_seconds=_time.perf_counter() - t0)
+        with span("oobleck.plan.adapt") as sp:
+            plan = cm_adapt.plan_adaptation(
+                self.instances, self.batch.num_microbatches, sorted(dead),
+                self.config.global_batch, self.config.microbatch)
+        return dataclasses.replace(plan, replan_seconds=sp.seconds)
 
     def apply_adaptation(self, plan: AdaptPlan, dead: Set[str] = frozenset(),
                          drained: bool = False) -> AdaptPlan:
@@ -366,45 +366,47 @@ class ConfigurationEngine:
         re-instantiation; only the dead slots' layer states are copied
         from surviving replicas.  Raises ``AdaptationError`` when there
         are not enough spares or a dead layer has no surviving owner."""
-        t0 = _time.perf_counter()
-        dead_active = sorted(d for d in dead if d in set(self.nodes))
-        spares = [n for n in self.spare_nodes if n not in dead]
-        if len(spares) < len(dead_active):
-            raise AdaptationError(
-                f"spare promotion infeasible: {len(dead_active)} dead "
-                f"slots, {len(spares)} spares")
-        replacement = dict(zip(dead_active, spares))
-        used = list(replacement.values())
-        owners = cm_sync.layer_owner_map(self.instances)
-        copy_plan: List[CopyTask] = []
-        load: Dict[str, int] = {}
-        new_instances: List[PipelineInstance] = []
-        for inst in self.instances:
-            if not (set(inst.nodes) & set(replacement)):
-                new_instances.append(inst)
-                continue
-            new_nodes = [replacement.get(n, n) for n in inst.nodes]
-            for layer in range(inst.template.num_layers):
-                for node in inst.layer_owners(layer):
-                    if node not in replacement:
-                        continue
-                    srcs = sorted(owners[layer] - set(dead_active))
-                    if not srcs:
-                        raise AdaptationError(
-                            f"spare promotion infeasible: layer {layer} "
-                            "has no surviving owner")
-                    src = min(srcs, key=lambda s: (load.get(s, 0), s))
-                    nbytes = _layer_state_bytes(self.profile, layer)
-                    load[src] = load.get(src, 0) + nbytes
-                    copy_plan.append(CopyTask(layer, src, replacement[node],
-                                              nbytes, sources=tuple(srcs)))
-            new_instances.append(PipelineInstance(
-                instance_id=inst.instance_id, template=inst.template,
-                nodes=new_nodes))
-        return ReconfigResult(
-            instances=new_instances, copy_plan=copy_plan, batch=self.batch,
-            spare_nodes=[n for n in spares if n not in used],
-            replan_seconds=_time.perf_counter() - t0)
+        with span("oobleck.plan.spare") as sp:
+            dead_active = sorted(d for d in dead if d in set(self.nodes))
+            spares = [n for n in self.spare_nodes if n not in dead]
+            if len(spares) < len(dead_active):
+                raise AdaptationError(
+                    f"spare promotion infeasible: {len(dead_active)} dead "
+                    f"slots, {len(spares)} spares")
+            replacement = dict(zip(dead_active, spares))
+            used = list(replacement.values())
+            owners = cm_sync.layer_owner_map(self.instances)
+            copy_plan: List[CopyTask] = []
+            load: Dict[str, int] = {}
+            new_instances: List[PipelineInstance] = []
+            for inst in self.instances:
+                if not (set(inst.nodes) & set(replacement)):
+                    new_instances.append(inst)
+                    continue
+                new_nodes = [replacement.get(n, n) for n in inst.nodes]
+                for layer in range(inst.template.num_layers):
+                    for node in inst.layer_owners(layer):
+                        if node not in replacement:
+                            continue
+                        srcs = sorted(owners[layer] - set(dead_active))
+                        if not srcs:
+                            raise AdaptationError(
+                                f"spare promotion infeasible: layer {layer} "
+                                "has no surviving owner")
+                        src = min(srcs, key=lambda s: (load.get(s, 0), s))
+                        nbytes = _layer_state_bytes(self.profile, layer)
+                        load[src] = load.get(src, 0) + nbytes
+                        copy_plan.append(CopyTask(
+                            layer, src, replacement[node], nbytes,
+                            sources=tuple(srcs)))
+                new_instances.append(PipelineInstance(
+                    instance_id=inst.instance_id, template=inst.template,
+                    nodes=new_nodes))
+            result = ReconfigResult(
+                instances=new_instances, copy_plan=copy_plan, batch=self.batch,
+                spare_nodes=[n for n in spares if n not in used])
+        result.replan_seconds = sp.seconds
+        return result
 
     def apply_spare_promotion(self, result: ReconfigResult,
                               dead: Set[str] = frozenset(),

@@ -24,11 +24,11 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import time as _time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from repro.core.batch import BatchPlan, distribute_batch
 from repro.core.templates import NodeSpec, PipelineTemplate, PlanningError
+from repro.utils.spans import span
 
 
 class InsufficientReplicasError(RuntimeError):
@@ -120,125 +120,133 @@ class Reconfigurator:
         nodes from an earlier reconfiguration; they enter the recovery
         pool like the survivors of a damaged pipeline, so they rejoin
         service whenever a covering combination exists."""
-        t0 = _time.perf_counter()
-        spec = self.spec
-        spares = [n for n in spares if n not in dead_nodes]
-        survivors: List[List[str]] = [
-            [n for n in inst.nodes if n not in dead_nodes] for inst in instances]
-        total = sum(len(s) for s in survivors) + len(spares)
-        if total < (spec.f + 1) * spec.n0:
-            raise InsufficientReplicasError(
-                f"{total} nodes < (f+1)*n0 = {(spec.f + 1) * spec.n0}; "
-                "checkpoint and exit")
+        with span("oobleck.plan.failure") as sp:
+            spec = self.spec
+            spares = [n for n in spares if n not in dead_nodes]
+            survivors: List[List[str]] = [
+                [n for n in inst.nodes if n not in dead_nodes]
+                for inst in instances]
+            total = sum(len(s) for s in survivors) + len(spares)
+            if total < (spec.f + 1) * spec.n0:
+                raise InsufficientReplicasError(
+                    f"{total} nodes < (f+1)*n0 = {(spec.f + 1) * spec.n0}; "
+                    "checkpoint and exit")
 
-        old_owners = self._ownership(instances)
-        result = ReconfigResult(instances=[], copy_plan=[], batch=None)  # type: ignore
+            old_owners = self._ownership(instances)
+            result = ReconfigResult(instances=[], copy_plan=[],
+                                    batch=None)  # type: ignore
 
-        healthy: List[Tuple[PipelineInstance, List[str]]] = []
-        damaged: List[List[str]] = []
-        for inst, nodes in zip(instances, survivors):
-            if len(nodes) == inst.template.num_nodes:
-                healthy.append((inst, nodes))
-            elif nodes:
-                damaged.append(nodes)
-        if spares:
-            damaged.append(list(spares))
-        # Damaged pipelines with zero survivors simply disappear.
+            healthy: List[Tuple[PipelineInstance, List[str]]] = []
+            damaged: List[List[str]] = []
+            for inst, nodes in zip(instances, survivors):
+                if len(nodes) == inst.template.num_nodes:
+                    healthy.append((inst, nodes))
+                elif nodes:
+                    damaged.append(nodes)
+            if spares:
+                damaged.append(list(spares))
+            # Damaged pipelines with zero survivors simply disappear.
 
-        new_instances: List[PipelineInstance] = [inst for inst, _ in healthy]
+            new_instances: List[PipelineInstance] = [
+                inst for inst, _ in healthy]
 
-        # --- step 1: simple reinstantiation -------------------------------
-        still_small: List[List[str]] = []
-        for nodes in damaged:
-            if len(nodes) >= spec.n0:
-                new_instances.append(self._instantiate(len(nodes), nodes))
-                result.reinstantiated += 1
-            else:
-                still_small.append(nodes)
+            # --- step 1: simple reinstantiation ---------------------------
+            still_small: List[List[str]] = []
+            for nodes in damaged:
+                if len(nodes) >= spec.n0:
+                    new_instances.append(self._instantiate(len(nodes), nodes))
+                    result.reinstantiated += 1
+                else:
+                    still_small.append(nodes)
 
-        # --- step 2: borrow nodes -----------------------------------------
-        for nodes in list(still_small):
-            need = spec.n0 - len(nodes)
-            borrowed: List[str] = []
-            # donors: largest pipelines first, may only shrink down to n0
-            donors = sorted(new_instances,
-                            key=lambda i: i.template.num_nodes, reverse=True)
-            for donor in donors:
-                while need and donor.template.num_nodes - 1 >= spec.n0:
-                    node = donor.nodes[-1]
-                    shrunk = self._instantiate(
-                        donor.template.num_nodes - 1, donor.nodes[:-1])
-                    new_instances[new_instances.index(donor)] = shrunk
-                    donor = shrunk
-                    borrowed.append(node)
-                    need -= 1
+            # --- step 2: borrow nodes -------------------------------------
+            for nodes in list(still_small):
+                need = spec.n0 - len(nodes)
+                borrowed: List[str] = []
+                # donors: largest pipelines first, may only shrink down to n0
+                donors = sorted(new_instances,
+                                key=lambda i: i.template.num_nodes,
+                                reverse=True)
+                for donor in donors:
+                    while need and donor.template.num_nodes - 1 >= spec.n0:
+                        node = donor.nodes[-1]
+                        shrunk = self._instantiate(
+                            donor.template.num_nodes - 1, donor.nodes[:-1])
+                        new_instances[new_instances.index(donor)] = shrunk
+                        donor = shrunk
+                        borrowed.append(node)
+                        need -= 1
+                    if not need:
+                        break
                 if not need:
-                    break
-            if not need:
-                new_instances.append(
-                    self._instantiate(spec.n0, nodes + borrowed))
-                result.borrowed += len(borrowed)
-                still_small.remove(nodes)
-            else:
-                # return any partial borrow is unnecessary: donors already
-                # reinstantiated smaller; just keep the pool for merging.
-                nodes.extend(borrowed)
-
-        # --- step 3: merge pipelines ---------------------------------------
-        while still_small:
-            nodes = still_small.pop()
-            pool = list(nodes)
-            while len(pool) < spec.n0:
-                if still_small:
-                    pool.extend(still_small.pop())
-                    continue
-                if not new_instances:
-                    raise InsufficientReplicasError(
-                        "no pipeline left to merge with")
-                # absorb the smallest healthy pipeline (Thm B.1: a template
-                # for the merged size exists)
-                victim = min(new_instances, key=lambda i: i.template.num_nodes)
-                new_instances.remove(victim)
-                pool.extend(victim.nodes)
-                result.merged += 1
-            size = len(pool)
-            if size in self.templates:
-                new_instances.append(self._instantiate(size, pool))
-            else:
-                # Thm B.1 guarantees a template for a merge of TWO pipelines
-                # below n_max, but a correlated burst (whole-rack failure,
-                # preemption wave) can leave a pool larger than the largest
-                # template after several absorptions.  Split the pool back
-                # into covered sizes instead of giving up — fewest pipelines
-                # first, so the merged capacity stays in deep/fast pipelines.
-                # A capped template set (sizes n0..n_max with n_max < 2n0-1)
-                # has gaps no decomposition covers; then the largest
-                # coverable prefix runs and the remainder waits as hot
-                # spares for the next join/reconfiguration.
-                parts, use = self._decompose_prefix(size)
-                if not parts:
-                    raise InsufficientReplicasError(
-                        f"merged pool of {size} nodes is below every "
-                        f"template size {sorted(self.templates)}")
-                cursor = 0
-                for part in parts:
                     new_instances.append(
-                        self._instantiate(part, pool[cursor:cursor + part]))
-                    cursor += part
-                result.spare_nodes.extend(pool[use:])
+                        self._instantiate(spec.n0, nodes + borrowed))
+                    result.borrowed += len(borrowed)
+                    still_small.remove(nodes)
+                else:
+                    # return any partial borrow is unnecessary: donors already
+                    # reinstantiated smaller; just keep the pool for merging.
+                    nodes.extend(borrowed)
 
-        # --- fault-tolerance floor: keep >= f+1 pipelines -------------------
-        if len(new_instances) < spec.f + 1:
-            new_instances = self._global_replan(
-                [n for inst in new_instances for n in inst.nodes])
-            result.globally_replanned = True
+            # --- step 3: merge pipelines ----------------------------------
+            while still_small:
+                nodes = still_small.pop()
+                pool = list(nodes)
+                while len(pool) < spec.n0:
+                    if still_small:
+                        pool.extend(still_small.pop())
+                        continue
+                    if not new_instances:
+                        raise InsufficientReplicasError(
+                            "no pipeline left to merge with")
+                    # absorb the smallest healthy pipeline (Thm B.1: a template
+                    # for the merged size exists)
+                    victim = min(new_instances,
+                                 key=lambda i: i.template.num_nodes)
+                    new_instances.remove(victim)
+                    pool.extend(victim.nodes)
+                    result.merged += 1
+                size = len(pool)
+                if size in self.templates:
+                    new_instances.append(self._instantiate(size, pool))
+                else:
+                    # Thm B.1 guarantees a template for a merge of TWO
+                    # pipelines below n_max, but a correlated burst
+                    # (whole-rack failure, preemption wave) can leave a pool
+                    # larger than the largest template after several
+                    # absorptions.  Split the pool back into covered sizes
+                    # instead of giving up — fewest pipelines first, so the
+                    # merged capacity stays in deep/fast pipelines.  A capped
+                    # template set (sizes n0..n_max with n_max < 2n0-1) has
+                    # gaps no decomposition covers; then the largest
+                    # coverable prefix runs and the remainder waits as hot
+                    # spares for the next join/reconfiguration.
+                    parts, use = self._decompose_prefix(size)
+                    if not parts:
+                        raise InsufficientReplicasError(
+                            f"merged pool of {size} nodes is below every "
+                            f"template size {sorted(self.templates)}")
+                    cursor = 0
+                    for part in parts:
+                        new_instances.append(
+                            self._instantiate(part,
+                                              pool[cursor:cursor + part]))
+                        cursor += part
+                    result.spare_nodes.extend(pool[use:])
 
-        result.instances = new_instances
-        result.copy_plan = self._copy_plan(old_owners, new_instances, dead_nodes)
-        result.batch = distribute_batch(
-            [i.template for i in new_instances], self.global_batch,
-            self.microbatch)
-        result.replan_seconds = _time.perf_counter() - t0
+            # --- fault-tolerance floor: keep >= f+1 pipelines -------------
+            if len(new_instances) < spec.f + 1:
+                new_instances = self._global_replan(
+                    [n for inst in new_instances for n in inst.nodes])
+                result.globally_replanned = True
+
+            result.instances = new_instances
+            result.copy_plan = self._copy_plan(old_owners, new_instances,
+                                               dead_nodes)
+            result.batch = distribute_batch(
+                [i.template for i in new_instances], self.global_batch,
+                self.microbatch)
+        result.replan_seconds = sp.seconds
         return result
 
     # ------------------------------------------------------------------
@@ -248,28 +256,29 @@ class Reconfigurator:
         use every node — instantiation is a table lookup (§4.2).  Counts
         beyond the original N may not be exactly coverable; the largest
         coverable subset is used and the rest stay as hot spares."""
-        t0 = _time.perf_counter()
-        all_nodes = [n for inst in instances for n in inst.nodes]
-        all_nodes.extend(new_nodes)
-        old_owners = self._ownership(instances)
-        new_instances, spares = None, []
-        for use in range(len(all_nodes), (self.spec.f + 1) * self.spec.n0 - 1,
-                         -1):
-            try:
-                new_instances = self._global_replan(all_nodes[:use])
-                spares = all_nodes[use:]
-                break
-            except PlanningError:
-                continue
-        if new_instances is None:
-            raise PlanningError("join re-plan found no coverable subset")
-        batch = distribute_batch([i.template for i in new_instances],
-                                 self.global_batch, self.microbatch)
-        return ReconfigResult(
-            instances=new_instances,
-            copy_plan=self._copy_plan(old_owners, new_instances, set()),
-            batch=batch, globally_replanned=True, spare_nodes=spares,
-            replan_seconds=_time.perf_counter() - t0)
+        with span("oobleck.plan.join") as sp:
+            all_nodes = [n for inst in instances for n in inst.nodes]
+            all_nodes.extend(new_nodes)
+            old_owners = self._ownership(instances)
+            new_instances, spares = None, []
+            for use in range(len(all_nodes),
+                             (self.spec.f + 1) * self.spec.n0 - 1, -1):
+                try:
+                    new_instances = self._global_replan(all_nodes[:use])
+                    spares = all_nodes[use:]
+                    break
+                except PlanningError:
+                    continue
+            if new_instances is None:
+                raise PlanningError("join re-plan found no coverable subset")
+            batch = distribute_batch([i.template for i in new_instances],
+                                     self.global_batch, self.microbatch)
+            result = ReconfigResult(
+                instances=new_instances,
+                copy_plan=self._copy_plan(old_owners, new_instances, set()),
+                batch=batch, globally_replanned=True, spare_nodes=spares)
+        result.replan_seconds = sp.seconds
+        return result
 
     # ------------------------------------------------------------------
     def _decompose_prefix(self, total: int) -> Tuple[List[int], int]:

@@ -248,13 +248,18 @@ def main(argv=None) -> dict:
                       f"on target hw, program cache: {info['cache']})")
             else:
                 xfer = info["transfer"]
+                phases = " ".join(f"{k} {v * 1e3:.1f}ms"
+                                  for k, v in info["phases"].items())
                 print(f"[fail] killed {victim}: recovered from replicas in "
                       f"{wall:.2f}s ({info['policy']}; "
                       f"copied {info['copied_bytes'] / 1e6:.0f}MB of state over "
                       f"{xfer['streams']} streams, "
                       f"{xfer['pod_local_fraction']:.0%} pod-local, modeled "
-                      f"transfer {xfer['seconds'] * 1e3:.1f}ms on target hw, "
-                      f"program cache: {info['cache']}), "
+                      f"transfer {xfer['seconds'] * 1e3:.1f}ms on target hw; "
+                      f"measured {phases}, "
+                      f"{info['state_copy_bytes'] / 1e6:.0f}MB copied on "
+                      f"device ({info['moved_state_bytes'] / 1e6:.0f}MB "
+                      f"moved), program cache: {info['cache']}), "
                       f"pipelines={[i.template.num_nodes for i in engine.instances]}")
         if step == args.join_at:
             raise SystemExit("join-at requires the elastic example; see "

@@ -82,6 +82,7 @@ from repro.runtime.coordination import (CoordinatorServer, DataServer,
                                         unpack_batches, unpack_tree)
 from repro.runtime.executor import CompileCounter, Executor, ProgramCache
 from repro.runtime.pipeline import HeteroTrainer
+from repro.utils.spans import span
 
 _RPC_TIMEOUT = float(os.environ.get("REPRO_DRYRUN_TIMEOUT", "600"))
 
@@ -312,7 +313,7 @@ class ShardTrainer(HeteroTrainer):
                     and self._old_lead.get(node) is not None
                     and self._old_lead[node] not in dead_ranks)
 
-        def state_for(node: str, l: int) -> Dict:
+        def state_for(node: str, l: int) -> Tuple[Dict, bool]:
             if avail(node, l):
                 src = node                  # state didn't move
             else:
@@ -327,17 +328,17 @@ class ShardTrainer(HeteroTrainer):
                     src = cands[0]
             src_rank = self._old_lead[src]
             if src_rank == self.rank:
-                return self._serve_view[(src, l)]
-            t0 = time.perf_counter()
-            reply, blobs = data_call(
-                data_addrs[src_rank],
-                {"type": "get_state", "node": src, "layer": l})
-            st = jax.tree.map(jnp.asarray, unpack_tree(
-                self._state_skeleton(l), reply["spec"], blobs))
+                return self._serve_view[(src, l)], src != node
+            with span("oobleck.multihost.fetch") as sp:
+                reply, blobs = data_call(
+                    data_addrs[src_rank],
+                    {"type": "get_state", "node": src, "layer": l})
+                st = jax.tree.map(jnp.asarray, unpack_tree(
+                    self._state_skeleton(l), reply["spec"], blobs))
             fetched["bytes"] += sum(len(b) for b in blobs)
             fetched["fetches"] += 1
-            fetched["seconds"] += time.perf_counter() - t0
-            return st
+            fetched["seconds"] += sp.seconds
+            return st, src != node
 
         self.runs = [self._bind_run(inst, layers=None, state_fn=state_for)
                      for inst in self._bound_instances()]
@@ -744,49 +745,50 @@ class MultiHostExecutor(Executor):
         alive = self.server.alive_ranks()
         # PREPARE: dry-run locally + on every survivor; fingerprints
         # must agree before anything mutates
-        t0 = time.perf_counter()
-        dead_active = {d for d in dead if d in set(self.engine.nodes)}
-        if dead_active:
-            spares = [n for n in self.engine.spare_nodes if n not in dead]
-            my_fp = self.engine.plan_fingerprint(
-                self.engine.reconf.on_failure(self.engine.instances,
-                                              dead_active, spares=spares))
-        else:
-            my_fp = self.engine.plan_fingerprint()
-        replies = self.server.broadcast_call(
-            {"type": "reconf_prepare", "dead": sorted(dead),
-             "kind": "fail"}, ranks=alive, timeout=self.rpc_timeout)
-        for r, (h, _) in replies.items():
-            if h["fingerprint"] != my_fp:
-                raise EpochMismatch(
-                    f"PREPARE: rank {r} planned {h['fingerprint']}, "
-                    f"coordinator planned {my_fp}")
-        replan_s = time.perf_counter() - t0
+        with span("oobleck.multihost.replan") as replan:
+            dead_active = {d for d in dead if d in set(self.engine.nodes)}
+            if dead_active:
+                spares = [n for n in self.engine.spare_nodes
+                          if n not in dead]
+                my_fp = self.engine.plan_fingerprint(
+                    self.engine.reconf.on_failure(self.engine.instances,
+                                                  dead_active,
+                                                  spares=spares))
+            else:
+                my_fp = self.engine.plan_fingerprint()
+            replies = self.server.broadcast_call(
+                {"type": "reconf_prepare", "dead": sorted(dead),
+                 "kind": "fail"}, ranks=alive, timeout=self.rpc_timeout)
+            for r, (h, _) in replies.items():
+                if h["fingerprint"] != my_fp:
+                    raise EpochMismatch(
+                        f"PREPARE: rank {r} planned {h['fingerprint']}, "
+                        f"coordinator planned {my_fp}")
         # COMMIT: everyone applies the agreed plan; state moves between
         # processes over the data plane
-        t1 = time.perf_counter()
-        result = self.engine.handle_failure(dead, drained=drained)
-        replies = self.server.broadcast_call(
-            {"type": "reconf_commit", "dead": sorted(dead), "kind": "fail",
-             "drained": drained}, ranks=alive, timeout=self.rpc_timeout)
-        info = self._check_commit(replies)
-        commit_s = time.perf_counter() - t1
+        with span("oobleck.multihost.commit") as commit:
+            result = self.engine.handle_failure(dead, drained=drained)
+            replies = self.server.broadcast_call(
+                {"type": "reconf_commit", "dead": sorted(dead),
+                 "kind": "fail", "drained": drained},
+                ranks=alive, timeout=self.rpc_timeout)
+            info = self._check_commit(replies)
         # FINISH: agreed epoch everywhere — drop serving views
-        t2 = time.perf_counter()
-        self.server.broadcast_call({"type": "reconf_finish"}, ranks=alive,
-                                   timeout=self.rpc_timeout)
-        barrier_s = time.perf_counter() - t2
+        with span("oobleck.multihost.barrier") as barrier:
+            self.server.broadcast_call({"type": "reconf_finish"},
+                                       ranks=alive,
+                                       timeout=self.rpc_timeout)
         self.last_info = {
             "policy": "replan", "copied_bytes": result.copy_bytes(),
             "fetched_bytes": info["fetched_bytes"],
             "fetches": info["fetches"],
             "num_pipelines": len(self.engine.instances),
             "epoch": self.engine.epoch,
-            "breakdown": {"replan": replan_s,
+            "breakdown": {"replan": replan.seconds,
                           "transfer": info["transfer_s"],
                           "compile": 0.0,
-                          "commit": commit_s,
-                          "barrier": barrier_s}}
+                          "commit": commit.seconds,
+                          "barrier": barrier.seconds}}
         return self.last_info
 
     def join(self, nodes: List[str]) -> Dict:
@@ -798,19 +800,17 @@ class MultiHostExecutor(Executor):
         hosting_update = {n: alive[i % len(alive)]
                           for i, n in enumerate(nodes)}
         self.hosting.update(hosting_update)
-        t0 = time.perf_counter()
-        self.server.broadcast_call(
-            {"type": "reconf_prepare", "dead": [], "kind": "join",
-             "hosting_update": hosting_update},
-            ranks=alive, timeout=self.rpc_timeout)
-        replan_s = time.perf_counter() - t0
-        t1 = time.perf_counter()
-        result = self.engine.handle_join(list(nodes))
-        replies = self.server.broadcast_call(
-            {"type": "reconf_commit", "dead": [], "kind": "join",
-             "nodes": nodes}, ranks=alive, timeout=self.rpc_timeout)
-        info = self._check_commit(replies)
-        commit_s = time.perf_counter() - t1
+        with span("oobleck.multihost.replan") as replan:
+            self.server.broadcast_call(
+                {"type": "reconf_prepare", "dead": [], "kind": "join",
+                 "hosting_update": hosting_update},
+                ranks=alive, timeout=self.rpc_timeout)
+        with span("oobleck.multihost.commit") as commit:
+            result = self.engine.handle_join(list(nodes))
+            replies = self.server.broadcast_call(
+                {"type": "reconf_commit", "dead": [], "kind": "join",
+                 "nodes": nodes}, ranks=alive, timeout=self.rpc_timeout)
+            info = self._check_commit(replies)
         self.server.broadcast_call({"type": "reconf_finish"}, ranks=alive,
                                    timeout=self.rpc_timeout)
         self.last_info = {
@@ -818,9 +818,9 @@ class MultiHostExecutor(Executor):
             "fetched_bytes": info["fetched_bytes"],
             "num_pipelines": len(self.engine.instances),
             "epoch": self.engine.epoch,
-            "breakdown": {"replan": replan_s,
+            "breakdown": {"replan": replan.seconds,
                           "transfer": info["transfer_s"],
-                          "compile": 0.0, "commit": commit_s}}
+                          "compile": 0.0, "commit": commit.seconds}}
         return self.last_info
 
     def _check_commit(self, replies) -> Dict:

@@ -62,6 +62,7 @@ from repro.runtime.executor import (Executor, ProgramCache,
 from repro.runtime.schedule import flat_schedule
 from repro.runtime.sync_exec import (BucketedSync, perlayer_global_sumsq,
                                      perlayer_sync)
+from repro.utils.spans import span
 
 LayerState = Dict[str, Any]     # {"p": params, "m": moment1, "v": moment2}
 
@@ -252,9 +253,15 @@ class HeteroTrainer(Executor):
 
     # ------------------------------------------------------------------
     def _bind_run(self, inst: PipelineInstance, layers: Optional[List[Dict]],
-                  source_states: Optional[Dict[int, LayerState]] = None,
-                  state_fn: Optional[Callable[[str, int], LayerState]] = None
-                  ) -> PipelineRun:
+                  state_fn: Optional[Callable[[str, int],
+                                              Tuple[LayerState, bool]]] = None,
+                  copied: Optional[Dict[str, int]] = None) -> PipelineRun:
+        """Bind one pipeline's layer states: fresh from ``layers`` (zero
+        moments), or, on the data-plane path, from ``state_fn(node,
+        layer) -> (state, moved)``, where ``moved`` says the state comes
+        from another node than the layer's owner.  ``copied`` counts the
+        bytes copied on the device (``state_copy_bytes``) and those of
+        moved layers (``moved_state_bytes``)."""
         stage_layers = [list(range(st.layer_start, st.layer_end))
                         for st in inst.template.stages]
         states: Dict[int, LayerState] = {}
@@ -262,29 +269,26 @@ class HeteroTrainer(Executor):
             for l in lids:
                 # ALWAYS copy: update programs donate their input
                 # buffers, so replicas must never alias layer state
-                if state_fn is not None:
-                    # data-plane path: the state a layer's owning node
-                    # receives comes from the SCHEDULED source replica
-                    src = state_fn(inst.layer_owners(l)[0], l)
-                    states[l] = {"p": jax.tree.map(jnp.copy, src["p"]),
-                                 "m": jax.tree.map(jnp.copy, src["m"]),
-                                 "v": jax.tree.map(jnp.copy, src["v"])}
-                elif source_states is not None and l in source_states:
-                    src = source_states[l]
-                    states[l] = {"p": jax.tree.map(jnp.copy, src["p"]),
-                                 "m": jax.tree.map(jnp.copy, src["m"]),
-                                 "v": jax.tree.map(jnp.copy, src["v"])}
-                else:
+                if state_fn is None:
                     p = layers[l]
                     states[l] = {"p": jax.tree.map(jnp.copy, p),
                                  "m": zeros_like_tree(p),
                                  "v": zeros_like_tree(p)}
+                    continue
+                # data-plane path: the state a layer's owning node
+                # receives comes from the SCHEDULED source replica
+                src, moved = state_fn(inst.layer_owners(l)[0], l)
+                states[l] = {k: jax.tree.map(jnp.copy, src[k])
+                             for k in ("p", "m", "v")}
+                if copied is not None:
+                    nbytes = sum(leaf.nbytes
+                                 for leaf in jax.tree.leaves(states[l]))
+                    copied["state_copy_bytes"] += nbytes
+                    if moved:
+                        copied["moved_state_bytes"] += nbytes
         fns = [make_stage_fn(self.model, [self._kind[l] for l in lids])
                for lids in stage_layers]
         return PipelineRun(inst, stage_layers, states, fns)
-
-    # keep the historical name for callers/tests
-    _bind = _bind_run
 
     # ------------------------------------------------------------------
     # Program cache plumbing
@@ -331,7 +335,7 @@ class HeteroTrainer(Executor):
         def build() -> Callable:
             layer_cfg = dataclasses.replace(self.opt_cfg, clip_norm=0.0)
 
-            def upd(st, g, scale, step):
+            def layer_update(st, g, scale, step):
                 g = jax.tree.map(lambda t: t * scale, g)
                 new_p, new_opt, _ = adamw.apply(
                     layer_cfg, st["p"], g,
@@ -340,7 +344,7 @@ class HeteroTrainer(Executor):
 
             scale_aval = jax.ShapeDtypeStruct((), jnp.float32)
             step_aval = jax.ShapeDtypeStruct((), jnp.int32)
-            return jax.jit(upd, donate_argnums=(0,)).lower(
+            return jax.jit(layer_update, donate_argnums=(0,)).lower(
                 s_aval, g_aval, scale_aval, step_aval).compile()
 
         return self.cache.get_or_build(key, build)
@@ -454,13 +458,14 @@ class HeteroTrainer(Executor):
     # ------------------------------------------------------------------
     def _run_compiled(self, run: PipelineRun, microbatches: List[Dict]
                       ) -> Tuple[Dict[int, Any], jax.Array]:
-        tokens = jnp.stack([jnp.asarray(b["tokens"])
-                            for b in microbatches]).astype(jnp.int32)
-        labels = jnp.stack([jnp.asarray(b["labels"])
-                            for b in microbatches]).astype(jnp.int32)
-        fes = [b.get("frontend_embeds") for b in microbatches]
-        fe = (jnp.stack([jnp.asarray(f) for f in fes])
-              if fes[0] is not None else None)
+        with span("oobleck.step.inputs"):
+            tokens = jnp.stack([jnp.asarray(b["tokens"])
+                                for b in microbatches]).astype(jnp.int32)
+            labels = jnp.stack([jnp.asarray(b["labels"])
+                                for b in microbatches]).astype(jnp.int32)
+            fes = [b.get("frontend_embeds") for b in microbatches]
+            fe = (jnp.stack([jnp.asarray(f) for f in fes])
+                  if fes[0] is not None else None)
         prog = self._grads_program(
             run.signature, _avals_of(tokens), _avals_of(labels),
             _avals_of(fe) if fe is not None else None)
@@ -540,7 +545,8 @@ class HeteroTrainer(Executor):
         all_grads: List[Dict[int, Any]] = []
         nlls, weights = [], []
         for run, mbs in zip(self.runs, per_pipeline_batches):
-            g, nll = self._run_pipeline(run, mbs)
+            with span("oobleck.step.grads"):
+                g, nll = self._run_pipeline(run, mbs)
             all_grads.append(g)
             nlls.append(nll)
             weights.append(len(mbs))
@@ -566,40 +572,45 @@ class HeteroTrainer(Executor):
         ``"perlayer"`` is the eager per-layer oracle."""
         if self.sync_mode == "bucketed":
             plan = self._bucket_plan()
-            red = self._bsync.reduce(plan, all_grads, weights)
-            sq = jnp.zeros((), jnp.float32)
-            for s in red.sumsqs:
-                sq = sq + s
-            grad_norm = jnp.sqrt(sq)
-            scale = self._clip_scale(grad_norm)
+            with span("oobleck.step.sync"):
+                red = self._bsync.reduce(plan, all_grads, weights)
+                sq = jnp.zeros((), jnp.float32)
+                for s in red.sumsqs:
+                    sq = sq + s
+                grad_norm = jnp.sqrt(sq)
+                scale = self._clip_scale(grad_norm)
             if self.on_phase is not None:
                 self.on_phase("sync")
             # ---- commit phase: the ONLY mutating part of the step ----
-            self._bsync.commit_residuals(red)
-            step_in = self.opt_step             # adamw.apply increments
-            self.opt_step = self.opt_step + 1
-            for run in self.runs:
-                self._bsync.update(plan, red.flats, run.states, scale,
-                                   step_in)
+            with span("oobleck.step.update"):
+                self._bsync.commit_residuals(red)
+                step_in = self.opt_step         # adamw.apply increments
+                self.opt_step = self.opt_step + 1
+                for run in self.runs:
+                    self._bsync.update(plan, red.flats, run.states, scale,
+                                       step_in)
             return grad_norm
 
         # ---- per-layer oracle (Figure 9, the pre-§10 runtime path) ----
-        synced = perlayer_sync(all_grads, weights, self.num_layers)
+        with span("oobleck.step.sync"):
+            synced = perlayer_sync(all_grads, weights, self.num_layers)
+            # global-norm clip across the WHOLE model (clipping per layer
+            # would diverge from the SPMD fast path); all-device
+            # arithmetic: the scale is folded into the compiled update,
+            # never forced to the host
+            grad_norm = jnp.sqrt(perlayer_global_sumsq(synced,
+                                                       self.num_layers))
+            scale = self._clip_scale(grad_norm)
         if self.on_phase is not None:
             self.on_phase("sync")
-        # global-norm clip across the WHOLE model (clipping per layer
-        # would diverge from the SPMD fast path); all-device arithmetic:
-        # the scale is folded into the compiled update, never forced to
-        # the host
-        grad_norm = jnp.sqrt(perlayer_global_sumsq(synced, self.num_layers))
-        scale = self._clip_scale(grad_norm)
-        step_in = self.opt_step                 # adamw.apply increments
-        self.opt_step = self.opt_step + 1
-        for run in self.runs:
-            for l in sorted(run.states):
-                st = run.states[l]
-                prog = self._update_program(st, synced[l])
-                run.states[l] = prog(st, synced[l], scale, step_in)
+        with span("oobleck.step.update"):
+            step_in = self.opt_step             # adamw.apply increments
+            self.opt_step = self.opt_step + 1
+            for run in self.runs:
+                for l in sorted(run.states):
+                    st = run.states[l]
+                    prog = self._update_program(st, synced[l])
+                    run.states[l] = prog(st, synced[l], scale, step_in)
         return grad_norm
 
     def _clip_scale(self, grad_norm: jax.Array) -> jax.Array:
@@ -631,62 +642,84 @@ class HeteroTrainer(Executor):
                         by_node.setdefault(node, {})[l] = st
         return by_node
 
-    def _apply_transfer_plan(self, result, by_node: Dict[str, Dict[int, LayerState]],
-                             dead: Set[str]) -> Dict:
+    def _apply_transfer_plan(self, result, dead: Set[str],
+                             phases: Dict[str, float]) -> Dict:
         """Rebind every pipeline, sourcing each moved layer from the
         replica the transfer scheduler routed it from (pod-local first,
-        least-loaded sender), then swap programs by cache lookup."""
-        # (schedule_transfers already validated the plan against ``dead``
-        # and the copy plan's byte total)
-        plan = self.engine.transfer_plan(result, dead=dead)
-        fallback: Dict[int, LayerState] = {}
-        for node_states in by_node.values():
-            for l, st in node_states.items():
-                fallback.setdefault(l, st)
-        missing = [l for l in range(self.num_layers) if l not in fallback]
-        assert not missing, f"layers {missing} lost (>f failures in a stage)"
+        least-loaded sender), then swap programs by cache lookup.  Runs
+        the transfer_plan, copy and bind phases into ``phases``."""
+        # (schedule_transfers validates the plan against ``dead`` and the
+        # copy plan's byte total)
+        with span("oobleck.recover.transfer_plan") as sp:
+            plan = self.engine.transfer_plan(result, dead=dead)
+            stats = plan.stats()      # prices the makespan once
+        phases["transfer_plan"] = sp.seconds
+        copied = {"state_copy_bytes": 0, "moved_state_bytes": 0}
+        with span("oobleck.recover.copy") as sp:
+            # the runs still hold the instances from before the replan
+            by_node = self._states_by_node(exclude=dead)
+            fallback: Dict[int, LayerState] = {}
+            for node_states in by_node.values():
+                for l, st in node_states.items():
+                    fallback.setdefault(l, st)
+            missing = [l for l in range(self.num_layers)
+                       if l not in fallback]
+            assert not missing, \
+                f"layers {missing} lost (>f failures in a stage)"
 
-        def state_for(node: str, layer: int) -> LayerState:
-            held = by_node.get(node, {})
-            if layer in held:          # the node already owns this layer
-                return held[layer]
-            src = plan.source_of(node, layer)
-            if src is not None and layer in by_node.get(src, {}):
-                return by_node[src][layer]
-            return fallback[layer]
+            def state_for(node: str, layer: int) -> Tuple[LayerState, bool]:
+                held = by_node.get(node, {})
+                if layer in held:      # the node already owns this layer
+                    return held[layer], False
+                src = plan.source_of(node, layer)
+                if src is not None and layer in by_node.get(src, {}):
+                    return by_node[src][layer], True
+                return fallback[layer], True
 
-        self.runs = [self._bind_run(inst, layers=None, state_fn=state_for)
-                     for inst in self._bound_instances()]
-        self.bind()        # swap programs by lookup (zero compiles if warm)
-        stats = plan.stats()      # prices the makespan once
+            self.runs = [self._bind_run(inst, layers=None,
+                                        state_fn=state_for, copied=copied)
+                         for inst in self._bound_instances()]
+        phases["copy"] = sp.seconds
+        with span("oobleck.recover.bind") as sp:
+            self.bind()    # swap programs by lookup (zero compiles if warm)
+        phases["bind"] = sp.seconds
         return {"copied_bytes": result.copy_bytes(),
                 "num_pipelines": len(self.runs),
                 "cache": self.cache.stats.as_dict(),
                 "transfer": stats,
                 "breakdown": {"replan": result.replan_seconds,
-                              "transfer": stats["seconds"],
-                              "compile": 0.0}}
+                              "transfer": stats["seconds"]},
+                **copied}
 
-    def _apply_adaptation(self, plan, dead: Set[str],
-                          drained: bool = False) -> Dict:
-        """Commit a ReCycle adaptation: drop the damaged replicas' runs,
-        keep the survivors' layer states untouched (every replica holds
-        the full model, so re-routed microbatches compute the same math
-        on the host), and rebind — programs for the survivors' new
-        microbatch counts are already warm, so this is copy-free AND
-        compile-free."""
-        # price the reroute exposure against the replan alternative
-        ref_iter = self.engine.adaptation_reference_iteration(dead)
-        breakdown = self.engine.adapt_cost_model().breakdown(plan, ref_iter)
-        kept = {id(inst) for inst in plan.instances}
-        self.engine.apply_adaptation(plan, dead=dead, drained=drained)
-        self.runs = [run for run in self.runs if id(run.instance) in kept]
-        self.bind()        # pure cache lookups after warm_templates()
-        return {"policy": "adapt", "copied_bytes": 0,
-                "num_pipelines": len(self.runs),
+    def _apply_adaptation(self, plan, breakdown: Dict[str, float],
+                          phases: Dict[str, float]) -> Dict:
+        """Commit a ReCycle adaptation the engine has applied: drop the
+        damaged replicas' runs, keep the survivors' layer states
+        untouched (every replica holds the full model, so re-routed
+        microbatches compute the same math on the host), and rebind —
+        programs for the survivors' new microbatch counts are already
+        warm, so this is copy-free AND compile-free."""
+        with span("oobleck.recover.bind") as sp:
+            kept = {id(inst) for inst in plan.instances}
+            self.runs = [run for run in self.runs
+                         if id(run.instance) in kept]
+            self.bind()    # pure cache lookups after warm_templates()
+        phases["bind"] = sp.seconds
+        return {"copied_bytes": 0, "num_pipelines": len(self.runs),
                 "parked_nodes": list(plan.parked_nodes),
                 "cache": self.cache.stats.as_dict(),
                 "breakdown": breakdown}
+
+    def _event_info(self, info: Dict, phases: Dict[str, float],
+                    compiles: int) -> Dict:
+        """What every recovery/join reports besides its path's own keys:
+        the measured ``phases`` (seconds; ``replan`` spans the policy
+        choice as well as the planner's own ``breakdown["replan"]``) and
+        ``breakdown["compile"]``, the ProgramCache misses the event
+        caused (0 on a warm cache)."""
+        info["phases"] = phases
+        info["breakdown"]["compile"] = self.cache.stats.compiles - compiles
+        return info
 
     def handle_failure(self, dead_nodes: set, drained: bool = False,
                        policy: Optional[str] = None) -> Dict:
@@ -697,47 +730,53 @@ class HeteroTrainer(Executor):
         dead = set(dead_nodes)
         policy = policy or getattr(self.engine.config,
                                    "recovery_policy", "replan")
-        decision = None
-        if policy == "auto":
-            decision = self.engine.select_recovery_policy(dead)
-            policy = decision["policy"]
+        compiles = self.cache.stats.compiles
+        with span("oobleck.recover.replan") as sp:
+            decision = adapt = None
+            if policy == "auto":
+                decision = self.engine.select_recovery_policy(dead)
+                policy = decision["policy"]
+            if policy == "adapt":
+                try:
+                    adapt = self.engine.plan_adaptation(dead)
+                    # price the reroute exposure against the replan
+                    # alternative, before the adaptation applies
+                    breakdown = self.engine.adapt_cost_model().breakdown(
+                        adapt, self.engine.adaptation_reference_iteration(
+                            dead))
+                    self.engine.apply_adaptation(adapt, dead=dead,
+                                                 drained=drained)
+                except AdaptationError:
+                    policy = "replan"
+            if policy == "spare":
+                try:
+                    result = self.engine.plan_spare_promotion(dead)
+                    self.engine.apply_spare_promotion(result, dead=dead,
+                                                      drained=drained)
+                except AdaptationError:
+                    policy = "replan"
+            if policy == "replan":
+                result = self.engine.handle_failure(dead, drained=drained)
+        phases = {"replan": sp.seconds}
         if policy == "adapt":
-            try:
-                plan = self.engine.plan_adaptation(dead)
-                info = self._apply_adaptation(plan, dead, drained=drained)
-                if decision is not None:
-                    info["decision"] = decision["policy"]
-                return info
-            except AdaptationError:
-                policy = "replan"
-        if policy == "spare":
-            try:
-                result = self.engine.plan_spare_promotion(dead)
-                by_node = self._states_by_node(exclude=dead)
-                self.engine.apply_spare_promotion(result, dead=dead,
-                                                  drained=drained)
-                info = self._apply_transfer_plan(result, by_node, dead)
-                info["policy"] = "spare"
-                if decision is not None:
-                    info["decision"] = decision["policy"]
-                return info
-            except AdaptationError:
-                policy = "replan"
-        by_node = self._states_by_node(exclude=dead)
-        result = self.engine.handle_failure(dead, drained=drained)
-        info = self._apply_transfer_plan(result, by_node, dead)
-        info["policy"] = "replan"
+            info = self._apply_adaptation(adapt, breakdown, phases)
+        else:
+            info = self._apply_transfer_plan(result, dead, phases)
+        info["policy"] = policy
         if decision is not None:
             info["decision"] = decision["policy"]
-        return info
+        return self._event_info(info, phases, compiles)
 
     def handle_join(self, new_nodes: list) -> Dict:
         """Elastic scale-up: re-plan globally over the larger cluster and
         seed every new pipeline's layer states from existing replicas
         (the same copy path as failure recovery — §5 applies to joins)."""
-        by_node = self._states_by_node()
-        result = self.engine.handle_join(list(new_nodes))
-        return self._apply_transfer_plan(result, by_node, set())
+        compiles = self.cache.stats.compiles
+        with span("oobleck.recover.replan") as sp:
+            result = self.engine.handle_join(list(new_nodes))
+        phases = {"replan": sp.seconds}
+        info = self._apply_transfer_plan(result, set(), phases)
+        return self._event_info(info, phases, compiles)
 
     def recover(self, dead: Set[str], drained: bool = False) -> Dict:
         return self.handle_failure(set(dead), drained=drained)

@@ -193,12 +193,12 @@ class BucketedSync:
         key = ("bpack", b.specs)
 
         def build() -> Callable:
-            def pack(layers):
+            def bucket_pack(layers):
                 parts = [jnp.ravel(leaf).astype(jnp.float32)
                          for lt in layers for leaf in jax.tree.leaves(lt)]
                 return jnp.concatenate(parts)
             avals = [self.layer_avals[l] for l in b.lids]
-            return jax.jit(pack).lower(avals).compile()
+            return jax.jit(bucket_pack).lower(avals).compile()
 
         return self.cache.get_or_build(key, build)
 
@@ -206,9 +206,11 @@ class BucketedSync:
         key = ("bscale", n)
 
         def build() -> Callable:
+            def bucket_scale(x, w):
+                return x * w
             flat = jax.ShapeDtypeStruct((n,), jnp.float32)
             w = jax.ShapeDtypeStruct((), jnp.float32)
-            return jax.jit(lambda x, w: x * w).lower(flat, w).compile()
+            return jax.jit(bucket_scale).lower(flat, w).compile()
 
         return self.cache.get_or_build(key, build)
 
@@ -216,8 +218,10 @@ class BucketedSync:
         key = ("badd", n)
 
         def build() -> Callable:
+            def bucket_add(acc, x):
+                return acc + x
             flat = jax.ShapeDtypeStruct((n,), jnp.float32)
-            return jax.jit(lambda acc, x: acc + x,
+            return jax.jit(bucket_add,
                            donate_argnums=(0,)).lower(flat, flat).compile()
 
         return self.cache.get_or_build(key, build)
@@ -226,9 +230,10 @@ class BucketedSync:
         key = ("bsumsq", n)
 
         def build() -> Callable:
+            def bucket_sumsq(x):
+                return jnp.sum(jnp.square(x))
             flat = jax.ShapeDtypeStruct((n,), jnp.float32)
-            return jax.jit(
-                lambda x: jnp.sum(jnp.square(x))).lower(flat).compile()
+            return jax.jit(bucket_sumsq).lower(flat).compile()
 
         return self.cache.get_or_build(key, build)
 
@@ -240,12 +245,13 @@ class BucketedSync:
         codec = self.codec
 
         def build() -> Callable:
-            def ef(c, res):
+            def bucket_ef(c, res):
                 c = c + res
                 sent = decode_flat(encode_flat(c, codec), codec)
                 return sent, c - sent
             flat = jax.ShapeDtypeStruct((n,), jnp.float32)
-            return jax.jit(ef, donate_argnums=(0,)).lower(flat, flat).compile()
+            return jax.jit(bucket_ef, donate_argnums=(0,)).lower(
+                flat, flat).compile()
 
         return self.cache.get_or_build(key, build)
 
@@ -261,7 +267,7 @@ class BucketedSync:
         def build() -> Callable:
             layer_cfg = dataclasses.replace(self.opt_cfg, clip_norm=0.0)
 
-            def upd(states, flat, scale, step):
+            def bucket_update(states, flat, scale, step):
                 out, off = [], 0
                 for st in states:
                     leaves, treedef = jax.tree_util.tree_flatten(st["p"])
@@ -282,7 +288,7 @@ class BucketedSync:
             flat_aval = jax.ShapeDtypeStruct((b.n,), jnp.float32)
             scalar = jax.ShapeDtypeStruct((), jnp.float32)
             step_aval = jax.ShapeDtypeStruct((), jnp.int32)
-            return jax.jit(upd, donate_argnums=(0,)).lower(
+            return jax.jit(bucket_update, donate_argnums=(0,)).lower(
                 states_aval, flat_aval, scalar, step_aval).compile()
 
         return self.cache.get_or_build(key, build)
