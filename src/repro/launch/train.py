@@ -259,7 +259,8 @@ def main(argv=None) -> dict:
                       f"measured {phases}, "
                       f"{info['state_copy_bytes'] / 1e6:.0f}MB copied on "
                       f"device ({info['moved_state_bytes'] / 1e6:.0f}MB "
-                      f"moved), program cache: {info['cache']}), "
+                      f"moved), {info['in_place_bytes'] / 1e6:.0f}MB bound "
+                      f"in place, program cache: {info['cache']}), "
                       f"pipelines={[i.template.num_nodes for i in engine.instances]}")
         if step == args.join_at:
             raise SystemExit("join-at requires the elastic example; see "

@@ -334,14 +334,13 @@ class ShardTrainer(HeteroTrainer):
                     data_addrs[src_rank],
                     {"type": "get_state", "node": src, "layer": l})
                 st = jax.tree.map(jnp.asarray, unpack_tree(
-                    self._state_skeleton(l), reply["spec"], blobs))
+                    self._state_aval(l), reply["spec"], blobs))
             fetched["bytes"] += sum(len(b) for b in blobs)
             fetched["fetches"] += 1
             fetched["seconds"] += sp.seconds
             return st, src != node
 
-        self.runs = [self._bind_run(inst, layers=None, state_fn=state_for)
-                     for inst in self._bound_instances()]
+        self._rebind(state_for)
         self.bind()     # program swap by cache lookup (zero compiles)
         return {"copied_bytes": result.copy_bytes(),
                 "fetched_bytes": fetched["bytes"],
@@ -355,12 +354,6 @@ class ShardTrainer(HeteroTrainer):
         self._old_lead = {}
         self._old_owns = set()
         self._old_owners = {}
-
-    def _state_skeleton(self, l: int) -> Dict:
-        p = self._layer_avals[l]
-        f32 = lambda t: jax.ShapeDtypeStruct(t.shape, jnp.float32)
-        return {"p": p, "m": jax.tree.map(f32, p),
-                "v": jax.tree.map(f32, p)}
 
     def layer_hashes(self) -> Dict[int, Dict[int, str]]:
         out: Dict[int, Dict[int, str]] = {}

@@ -26,10 +26,12 @@ the paper's unit of state).  A training step:
      eager oracle path) — replicas stay bit-identical, which is what
      makes step 4 sound;
   4. on failure: the core engine reinstantiates pipelines from templates
-     and emits a copy plan; we rebuild stage arrays by copying layer
-     states (params AND moments) from surviving replicas — recovery
-     without any checkpoint, the paper's headline mechanism — and the
-     new pipeline set's programs come straight from the cache.
+     and emits a copy plan; we rebuild stage arrays from the surviving
+     replicas' layer states (params AND moments): a state that stays on
+     its node is bound in place, one that moves is copied by one
+     compiled program per layer — recovery without any checkpoint, the
+     paper's headline mechanism — and the new pipeline set's programs
+     come straight from the cache.
 
 ``mode="eager"`` keeps the original per-microbatch ``jax.vjp``-chain
 schedule walker as the parity reference (it shares the sync/update path
@@ -42,6 +44,7 @@ path (runtime/spmd.py) covers the homogeneous zero-failure case.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import jax
@@ -91,6 +94,12 @@ def split_into_layers(model: Model, params: Dict) -> List[Dict]:
 
 def zeros_like_tree(tree):
     return jax.tree.map(lambda t: jnp.zeros_like(t, dtype=jnp.float32), tree)
+
+
+def layer_copy(state: LayerState) -> LayerState:
+    """Every leaf of one layer state into a new buffer (the body of the
+    recovery copy program)."""
+    return jax.tree.map(jnp.copy, state)
 
 
 # shared with the sync data plane's program keys (runtime/executor.py)
@@ -235,6 +244,14 @@ class HeteroTrainer(Executor):
         # shape/dtype skeleton of every layer: lets warm() compile
         # programs for templates that are not currently instantiated
         self._layer_avals = [_avals_of(l) for l in layers]
+        # each layer state's structure key and bytes, spelled out once:
+        # the copy phase reads both per bound state
+        self._state_specs = [_tree_spec(self._state_aval(l))
+                             for l in range(self.num_layers)]
+        self._state_bytes = [
+            sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+                for a in jax.tree.leaves(self._state_aval(l)))
+            for l in range(self.num_layers)]
         self._bsync = BucketedSync(self.cache, opt_cfg, self._layer_avals,
                                    codec=codec)
         self._bucket_plan_cache = None   # rebuilt whenever bind() runs
@@ -253,42 +270,63 @@ class HeteroTrainer(Executor):
 
     # ------------------------------------------------------------------
     def _bind_run(self, inst: PipelineInstance, layers: Optional[List[Dict]],
-                  state_fn: Optional[Callable[[str, int],
-                                              Tuple[LayerState, bool]]] = None,
-                  copied: Optional[Dict[str, int]] = None) -> PipelineRun:
+                  state_fn: Optional[Callable[[str, int], LayerState]] = None
+                  ) -> PipelineRun:
         """Bind one pipeline's layer states: fresh from ``layers`` (zero
-        moments), or, on the data-plane path, from ``state_fn(node,
-        layer) -> (state, moved)``, where ``moved`` says the state comes
-        from another node than the layer's owner.  ``copied`` counts the
-        bytes copied on the device (``state_copy_bytes``) and those of
-        moved layers (``moved_state_bytes``)."""
+        moments), or, on the data-plane path, ``state_fn(node, layer)``,
+        the state the layer's owning node binds (``_rebind``)."""
         stage_layers = [list(range(st.layer_start, st.layer_end))
                         for st in inst.template.stages]
         states: Dict[int, LayerState] = {}
         for lids in stage_layers:
             for l in lids:
-                # ALWAYS copy: update programs donate their input
-                # buffers, so replicas must never alias layer state
                 if state_fn is None:
+                    # one holder per buffer (``_rebind``): every replica
+                    # gets its own copy of the fresh params
                     p = layers[l]
                     states[l] = {"p": jax.tree.map(jnp.copy, p),
                                  "m": zeros_like_tree(p),
                                  "v": zeros_like_tree(p)}
-                    continue
-                # data-plane path: the state a layer's owning node
-                # receives comes from the SCHEDULED source replica
-                src, moved = state_fn(inst.layer_owners(l)[0], l)
-                states[l] = {k: jax.tree.map(jnp.copy, src[k])
-                             for k in ("p", "m", "v")}
-                if copied is not None:
-                    nbytes = sum(leaf.nbytes
-                                 for leaf in jax.tree.leaves(states[l]))
-                    copied["state_copy_bytes"] += nbytes
-                    if moved:
-                        copied["moved_state_bytes"] += nbytes
+                else:
+                    states[l] = state_fn(inst.layer_owners(l)[0], l)
         fns = [make_stage_fn(self.model, [self._kind[l] for l in lids])
                for lids in stage_layers]
         return PipelineRun(inst, stage_layers, states, fns)
+
+    def _rebind(self, state_fn: Callable[[str, int], Tuple[LayerState, bool]]
+                ) -> Dict[str, int]:
+        """Rebind every pipeline this process leads, each layer from
+        ``state_fn(node, layer) -> (state, moved)``, where ``moved`` says
+        the state comes from another node than the layer's owner.
+
+        One holder per buffer: update programs donate their input
+        buffers, so no two replicas may hold the same one.  A held state
+        (not moved) that no new run has taken yet is bound in place; any
+        other state is copied into new buffers by its layer's copy
+        program.  The old runs, dropped after the event, keep every held
+        state alive until then, so ids are stable.  Returns the bytes
+        bound in place (``in_place_bytes``), copied on the device
+        (``state_copy_bytes``) and, of those, of moved layers
+        (``moved_state_bytes``)."""
+        claimed: Set[int] = set()
+        counts = {"in_place_bytes": 0, "state_copy_bytes": 0,
+                  "moved_state_bytes": 0}
+
+        def bound(node: str, l: int) -> LayerState:
+            st, moved = state_fn(node, l)
+            nbytes = self._state_bytes[l]
+            if not moved and id(st) not in claimed:
+                claimed.add(id(st))
+                counts["in_place_bytes"] += nbytes
+                return st
+            counts["state_copy_bytes"] += nbytes
+            if moved:
+                counts["moved_state_bytes"] += nbytes
+            return self._copy_program(l)(st)
+
+        self.runs = [self._bind_run(inst, None, bound)
+                     for inst in self._bound_instances()]
+        return counts
 
     # ------------------------------------------------------------------
     # Program cache plumbing
@@ -349,6 +387,22 @@ class HeteroTrainer(Executor):
 
         return self.cache.get_or_build(key, build)
 
+    def _copy_program(self, l: int) -> Callable:
+        """Compiled copy of layer ``l``'s whole ``{p, m, v}`` state tree
+        into new buffers, cached per layer structure.  Nothing is
+        donated: the source stays live in its replica."""
+        key = ("lcopy", self._state_specs[l])
+        return self.cache.get_or_build(
+            key, lambda: jax.jit(layer_copy).lower(
+                self._state_aval(l)).compile())
+
+    def _state_aval(self, l: int) -> LayerState:
+        """Shape/dtype skeleton of layer ``l``'s state: params as built,
+        both Adam moments in float32."""
+        p = self._layer_avals[l]
+        f32 = lambda t: jax.ShapeDtypeStruct(t.shape, jnp.float32)
+        return {"p": p, "m": jax.tree.map(f32, p), "v": jax.tree.map(f32, p)}
+
     # ------------------------------------------------------------------
     # Warming: precompute-everything, execution edition
     # ------------------------------------------------------------------
@@ -387,14 +441,7 @@ class HeteroTrainer(Executor):
         # per-layer update path: seed every distinct layer structure
         # (embed / block / head)
         for l, aval in enumerate(self._layer_avals):
-            state_aval = {"p": aval,
-                          "m": jax.tree.map(
-                              lambda t: jax.ShapeDtypeStruct(
-                                  t.shape, jnp.float32), aval),
-                          "v": jax.tree.map(
-                              lambda t: jax.ShapeDtypeStruct(
-                                  t.shape, jnp.float32), aval)}
-            self._update_program(state_aval, aval)
+            self._update_program(self._state_aval(l), aval)
 
     def warm_templates(self, mb_counts: Optional[Iterable[int]] = None
                        ) -> Dict[str, int]:
@@ -440,6 +487,9 @@ class HeteroTrainer(Executor):
                 [l.param_bytes for l in self.engine.profile.layers],
                 self.engine.config.bucket_cap_bytes)
             self._warm_clip_glue()
+        # the recovery copy phase's per-layer-structure copy programs
+        for l in range(self.num_layers):
+            self._copy_program(l)
         self.bind()
         return self.cache.stats.as_dict()
 
@@ -654,7 +704,6 @@ class HeteroTrainer(Executor):
             plan = self.engine.transfer_plan(result, dead=dead)
             stats = plan.stats()      # prices the makespan once
         phases["transfer_plan"] = sp.seconds
-        copied = {"state_copy_bytes": 0, "moved_state_bytes": 0}
         with span("oobleck.recover.copy") as sp:
             # the runs still hold the instances from before the replan
             by_node = self._states_by_node(exclude=dead)
@@ -676,9 +725,7 @@ class HeteroTrainer(Executor):
                     return by_node[src][layer], True
                 return fallback[layer], True
 
-            self.runs = [self._bind_run(inst, layers=None,
-                                        state_fn=state_for, copied=copied)
-                         for inst in self._bound_instances()]
+            copied = self._rebind(state_for)
         phases["copy"] = sp.seconds
         with span("oobleck.recover.bind") as sp:
             self.bind()    # swap programs by lookup (zero compiles if warm)

@@ -165,6 +165,10 @@ def test_recover_step_is_recompile_free_for_warmed_set():
     # batch planner can emit for this global batch
     n_templates = len(trainer.engine.templates)
     assert stats["compiles"] >= n_templates * (GB // MB)
+    # ... and the recovery copy phase's copy program for each layer
+    # structure (embed, block, head)
+    copy_keys = [k for k in trainer.cache.keys() if k[0] == "lcopy"]
+    assert len(copy_keys) == 3
     src = SyntheticLM(arch.vocab_size, SEQ, seed=3)
     disp = GlobalBatchDispenser(src)
 
@@ -175,12 +179,24 @@ def test_recover_step_is_recompile_free_for_warmed_set():
     out = drive()                      # steady state: all ops traced once
     out["loss"].block_until_ready()
     victim = trainer.engine.instances[0].nodes[-1]
-    with track_compiles() as log:
-        trainer.recover({victim})
-        out = drive()
-        out["loss"].block_until_ready()
+    lowered = []
+
+    def on_event(name, secs, **kw):
+        if name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        with track_compiles() as log:
+            info = trainer.recover({victim})
+            out = drive()
+            out["loss"].block_until_ready()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert info["state_copy_bytes"] > 0      # the copy programs ran
     assert log.backend_compiles == 0, \
         f"{log.backend_compiles} XLA compiles during recover->step"
+    assert not lowered, f"{len(lowered)} programs lowered during recover->step"
 
 
 # ----------------------------------------------------------------------

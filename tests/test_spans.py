@@ -4,9 +4,10 @@
   1. a span makes no profiler call while no trace is collected, and
      writes nested annotations into a trace when one is;
   2. every recovery and join reports its measured phases, the bytes it
-     copied on the device and the bytes of layers that came from
-     another node — exactly, against the bound states and the old
-     ownership — and the ProgramCache misses it caused;
+     bound in place, the bytes it copied on the device and the bytes of
+     layers that came from another node — exactly, against the bound
+     states and the old ownership — and the ProgramCache misses it
+     caused;
   3. a step runs the compiled programs the bucket plan fixes (the count
      the benchmark's ``step_programs`` reads from the device trace), and
      every program carries its kind's name;
@@ -134,15 +135,21 @@ def test_recovery_reports_phases_and_exact_copy_counters(event):
     assert info["breakdown"]["compile"] == tr.cache.stats.compiles - compiles
     assert info["breakdown"]["compile"] > 0
 
-    copied = moved = 0
+    bound = in_place = moved = 0
     for run in tr.runs:
         for l, st in run.states.items():
-            copied += state_bytes(st)
-            if (run.instance.layer_owners(l)[0], l) not in held:
+            bound += state_bytes(st)
+            if (run.instance.layer_owners(l)[0], l) in held:
+                in_place += state_bytes(st)
+            else:
                 moved += state_bytes(st)
-    assert info["state_copy_bytes"] == copied
+    # every stage lives on one node, so each held state has one holder
+    # and is bound in place; only moved layers are copied
+    assert info["in_place_bytes"] == in_place
+    assert info["state_copy_bytes"] == bound - in_place
+    assert info["in_place_bytes"] + info["state_copy_bytes"] == bound
     assert info["moved_state_bytes"] == moved
-    assert 0 < moved < copied
+    assert 0 < info["moved_state_bytes"] <= info["state_copy_bytes"]
     # every moved layer is one the copy plan routes to its new owner
     routed = {(t.dst_node, t.layer)
               for t in tr.engine.last_reconfig.copy_plan}
@@ -191,10 +198,12 @@ def test_every_program_carries_its_kinds_name():
     names = {"grads": "jit_grads_fn", "bpack": "jit_bucket_pack",
              "bscale": "jit_bucket_scale", "badd": "jit_bucket_add",
              "bsumsq": "jit_bucket_sumsq", "bef": "jit_bucket_ef",
-             "bupdate": "jit_bucket_update", "update": "jit_layer_update"}
+             "bupdate": "jit_bucket_update", "update": "jit_layer_update",
+             "lcopy": "jit_layer_copy"}
     seen = set()
     for sync_mode, codec in [("bucketed", "int8"), ("perlayer", "none")]:
         arch, tr = make_trainer(sync_mode=sync_mode, codec=codec)
+        tr.recover({tr.engine.instances[0].nodes[-1]})
         for key, prog in tr.cache._programs.items():
             module = prog.as_text().split(None, 2)[1].rstrip(",")
             assert module == names[key[0]], key[0]
