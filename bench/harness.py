@@ -4,8 +4,10 @@ A cell (one ``workloads`` entry) names a configuration and a traffic mix.
 Everything that belongs to one of them is data in a file of its own:
 
 * ``bench/configs/<config>.json``: the model's sizes as run, the
-  deployment (nodes, fault tolerance, batch, kernels), the optimizer,
-  and the limits of the correctness check;
+  deployment (nodes, fault tolerance, batch, ``Model`` options), the
+  optimizer, the limits of the correctness check, and the files that
+  belong to the configuration: its plain reference (``reference``), its
+  FLOP count (``work``) and its test-sized copy (``test_copy``);
 * ``bench/traffic/<traffic>.json``: how data is drawn and which
   cluster events run between steps;
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric.
@@ -16,8 +18,7 @@ programs this cell's traffic reaches, takes the first steps that the
 correctness check compares, then measures ``trainer.step`` (and, where
 the traffic says, ``trainer.recover`` / ``trainer.join``) for a fixed
 number of seconds.  After the window it frees the system's state and
-trains the plain reference (``bench/reference.py``) over the same first
-steps.
+trains the configuration's plain reference over the same first steps.
 """
 from __future__ import annotations
 
@@ -32,7 +33,9 @@ import shutil
 import statistics
 import sys
 import time
+import typing
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -87,14 +90,24 @@ def resolve_cell(name: str, root: Path = ROOT,
                 root=root)
 
 
-def load_reader(metric: str, root: Path = ROOT) -> Callable:
-    """``bench/metrics/<metric>.py``'s ``read(ctx)``."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+def load_module(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str, root: Path = ROOT) -> Callable:
+    """``bench/metrics/<metric>.py``'s ``read(ctx)``."""
+    return load_module(root / "bench" / "metrics" / f"{metric}.py",
+                       f"bench_metric_{metric.replace('.', '_')}").read
+
+
+def config_module(cfg: Dict, key: str, root: Path = ROOT) -> ModuleType:
+    """The module that the configuration names under ``key``
+    (``reference``, ``work``), a path relative to the checkout's root."""
+    return load_module(root / cfg[key],
+                       f"bench_{key}_{cfg['name'].replace('.', '_')}")
 
 
 # ----------------------------------------------------------------------
@@ -165,44 +178,59 @@ class Events:
 # ----------------------------------------------------------------------
 # The system under test
 # ----------------------------------------------------------------------
-def model_dict(cfg: Dict) -> Dict:
-    """The sizes the reference reads."""
-    m = {k: cfg[k] for k in ("family", "num_layers", "d_model", "vocab_size",
-                             "rms_norm_eps", "tie_embeddings")}
-    if cfg["family"] == "ssm":
-        m["ssm"] = dict(cfg["ssm"])
-    else:
-        m.update({k: cfg[k] for k in ("num_heads", "head_dim", "d_ff",
-                                      "rope_theta")})
-    return m
-
-
-def init_fn(m: Dict) -> Callable:
-    """The weights from a key, made on the device in one jitted call."""
+def init_fn(ref: ModuleType, m: Dict) -> Callable:
+    """The weights from a key, made on the device in one jitted call by
+    the reference ``ref`` at its sizes ``m``."""
     import jax
-    from bench import reference
-    return jax.jit(lambda k: reference.init_params(m, k))
+    return jax.jit(lambda k: ref.init_params(m, k))
+
+
+def _dataclass_of(tp) -> Optional[type]:
+    """The dataclass that a field's type names, also inside Optional."""
+    for t in (tp, *typing.get_args(tp)):
+        if dataclasses.is_dataclass(t):
+            return t
+    return None
+
+
+def arch_config(cfg: Dict):
+    """The registered ``ArchConfig`` of ``cfg["arch"]`` with every field
+    that the configuration gives replaced; a nested group becomes the
+    dataclass that its field's type names (``ssm``, ``moe`` ...)."""
+    from repro.configs import get_arch
+    base = get_arch(cfg["arch"])
+    types = typing.get_type_hints(type(base))
+    sizes = {}
+    for f in dataclasses.fields(base):
+        if f.name in cfg:
+            cls, v = _dataclass_of(types[f.name]), cfg[f.name]
+            sizes[f.name] = cls(**v) if cls and isinstance(v, dict) else v
+    return dataclasses.replace(base, **sizes)
+
+
+def model_options(dep: Dict) -> Dict:
+    """``Model``'s keyword arguments from the deployment's keys that name
+    its fields (dtypes by their ``jax.numpy`` name); the benchmark always
+    runs without rematerialisation and with unscanned layers."""
+    import jax.numpy as jnp
+    from repro.models import Model
+    fields = {f.name for f in dataclasses.fields(Model)} - {"arch"}
+    opts = {k: getattr(jnp, v) if k.endswith("dtype") else v
+            for k, v in dep.items() if k in fields}
+    return {**opts, "remat": False, "scan_layers": False}
 
 
 def build_system(cfg: Dict, params):
     """(model, engine, trainer) as ``launch/train.py`` builds them, and a
     copy of the freshly planned engine for rehearsing events."""
-    from repro.configs import SSMConfig, get_arch
     from repro.core import EngineConfig, OobleckEngine, build_profile
     from repro.models import Model
     from repro.optim import adamw
     from repro.runtime import HeteroTrainer
-    import jax.numpy as jnp
 
-    fields = {f.name for f in dataclasses.fields(get_arch(cfg["arch"]))}
-    sizes = {k: v for k, v in cfg.items() if k in fields and k != "ssm"}
-    if "ssm" in cfg:
-        sizes["ssm"] = SSMConfig(**cfg["ssm"])
-    arch = dataclasses.replace(get_arch(cfg["arch"]), **sizes)
+    arch = arch_config(cfg)
     dep, opt = cfg["deployment"], cfg["optimizer"]
-    model = Model(arch, dtype=getattr(jnp, dep["dtype"]), remat=False,
-                  attn_impl=dep["attn_impl"], ssd_impl=dep["ssd_impl"],
-                  scan_layers=False)
+    model = Model(arch, **model_options(dep))
     profile = build_profile(arch, microbatch=dep["microbatch"],
                             seq_len=dep["seq_len"])
     nodes = [f"node{i}" for i in range(dep["nodes"])]
@@ -275,16 +303,16 @@ def state_norms(trainer, field: str, scale: float = 1.0) -> np.ndarray:
                      for r in trainer.runs])
 
 
-def change_norms(trainer, m: Dict, start) -> np.ndarray:
+def change_norms(trainer, ref: ModuleType, m: Dict, start) -> np.ndarray:
     """[replicas, leaves] norms of each replica's parameter change since
-    ``start`` (the full tree the system was built from)."""
+    ``start`` (the full tree the system was built from, split into
+    layers by the reference ``ref``)."""
     import jax
     import jax.numpy as jnp
-    from bench import reference
     fn = jax.jit(lambda a, tree: jnp.stack([
         jnp.sqrt(jnp.sum(jnp.square(x - y))) for x, y in zip(
             jax.tree.leaves(a),
-            jax.tree.leaves(reference.split_layers(m, tree)))]))
+            jax.tree.leaves(ref.split_layers(m, tree)))]))
     return np.stack([np.asarray(fn(_per_layer(r, "p", trainer.num_layers),
                                    start))
                      for r in trainer.runs])
@@ -382,15 +410,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     With ``trace``, the profiler writes under ``trace_dir`` (removed
     after the reduction)."""
     import jax
-    from bench import reference
 
     cfg, traffic = cell.config, cell.traffic
     dep = cfg["deployment"]
-    m = model_dict(cfg)
+    reference = config_module(cfg, "reference", cell.root)
+    m = reference.sizes(cfg)
     seq, rows, mb = dep["seq_len"], dep["global_batch"], dep["microbatch"]
     dev = jax.devices()[0]
 
-    init = init_fn(m)
+    init = init_fn(reference, m)
     params = init(weight_key(seed))
     model, engine, trainer, rehearsal = build_system(cfg, params)
     check_layout(model, params)
@@ -413,7 +441,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         if step < len(events.setup):
             events.apply(engine, trainer)
     readings = {"losses": np.asarray(losses), "grad_norms": grad_norms,
-                "change_norms": change_norms(trainer, m, params)}
+                "change_norms": change_norms(trainer, reference, m,
+                                            params)}
     names = program_leaf_names(trainer)
     del params
     gc.collect()
@@ -512,11 +541,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         t_red = time.perf_counter()
         red = trace_mod.reduce_dir(trace_dir, programs)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        from bench.peaks import peaks
-        ctx = {"cell": cell, "config": cfg, "window": window, "trace": red,
-               "peaks": peaks(dev.device_kind),
-               "chips": cell.chips,
-               "memory_peak_bytes": peak, "root": cell.root}
+        ctx = reader_context(cell, window, red, dev.device_kind, peak)
         per_layer = {}
         for metric in cell.per_layer:
             value = load_reader(metric["name"], cell.root)(ctx)
@@ -532,6 +557,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     result["window"] = window
     result["checks"] = checks
     return result
+
+
+def reader_context(cell: Cell, window: Dict, red, device_kind: str,
+                   memory_peak_bytes: int) -> Dict:
+    """What the per-layer readers read (``bench/metrics/_common.py``)."""
+    from bench.peaks import peaks
+    cfg = cell.config
+    work = config_module(cfg, "work", cell.root)
+    return {"cell": cell, "config": cfg, "window": window, "trace": red,
+            "peaks": peaks(device_kind), "chips": cell.chips,
+            "memory_peak_bytes": memory_peak_bytes, "root": cell.root,
+            "flops_per_token": work.flops_per_token(cfg)}
 
 
 def end_to_end(cell: Cell, window: Dict, setup_s: float) -> Dict:

@@ -1,12 +1,14 @@
 """Helpers the per-layer readers share.  A reader is
 ``bench/metrics/<metric>.py`` with ``read(ctx) -> float | None``; the
-context is built by ``bench.harness.run_cell`` after a traced window:
+context is built by ``bench.harness.reader_context`` after a traced window:
 
 * ``config``: the configuration file; ``window``: steps, tokens,
   seconds, the events' host timings and the compile count;
 * ``trace``: a ``bench.trace.Reduction`` of the window's trace;
 * ``peaks``: the device kind's row of ``bench/peaks.json``;
-* ``chips``, ``memory_peak_bytes``.
+* ``chips``, ``memory_peak_bytes``, ``root``;
+* ``flops_per_token``: the model FLOPs per token by the work model that
+  the configuration names (``work``).
 
 ``None`` means the reader found nothing to read in this run.
 """
@@ -20,13 +22,12 @@ SYNC_KINDS = ("bpack", "bscale", "badd", "bsumsq", "bef", "bupdate",
 
 
 def mfu(ctx: Dict) -> Optional[float]:
-    from bench.work.model import flops_per_token
     w = ctx["window"]
     if not w["steps"]:
         return None
     rate = w["tokens"] / w["seconds"]
     peak = ctx["peaks"]["bf16_flops"] * ctx["chips"]
-    return 100.0 * flops_per_token(ctx["config"]) * rate / peak
+    return 100.0 * ctx["flops_per_token"] * rate / peak
 
 
 def kernel_functions(kernel: str) -> set:

@@ -13,6 +13,8 @@ embedding, layers 1..L the blocks, layer L+1 the final norm and the head.
 A tied embedding is split into two copies that train apart from then on,
 which is what a pipeline whose first and last stages differ does.
 
+A configuration names this file as its ``reference``; ``sizes`` picks
+from the configuration file the sizes the equations below read.
 ``init_params`` makes the weights on the device, in one jitted call,
 from a key; the harness hands the same tree to the system and keeps it
 for the reference.  ``run_steps`` trains the reference for a few steps
@@ -28,6 +30,21 @@ from typing import Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# ----------------------------------------------------------------------
+# Sizes
+# ----------------------------------------------------------------------
+def sizes(cfg: Dict) -> Dict:
+    """The sizes the reference reads, from a configuration file."""
+    m = {k: cfg[k] for k in ("family", "num_layers", "d_model", "vocab_size",
+                             "rms_norm_eps", "tie_embeddings")}
+    if cfg["family"] == "ssm":
+        m["ssm"] = dict(cfg["ssm"])
+    else:
+        m.update({k: cfg[k] for k in ("num_heads", "head_dim", "d_ff",
+                                      "rope_theta")})
+    return m
 
 
 # ----------------------------------------------------------------------

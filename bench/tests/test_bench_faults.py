@@ -7,7 +7,7 @@ matmul precision reads about 1e-4 at the cells' sizes."""
 import time
 
 import pytest
-from bench.tests.tiny import tiny_cell, tiny_spec
+from bench.tests.tiny import tiny_cell
 
 from bench import faults, harness
 
@@ -23,6 +23,6 @@ def test_a_planted_fault_makes_the_run_incorrect(fault):
 @pytest.mark.parametrize("name", ["gpt3_medium.steady",
                                   "mamba2_780m.steady"])
 def test_the_bfloat16_control_is_incorrect(name):
-    cell = harness.resolve_cell(name, spec=tiny_spec())
+    cell = tiny_cell(name, committed_limits=False)
     checks = faults.control(cell, 2**32 + 3, 0.2)
     assert not harness.is_correct(checks), checks

@@ -36,6 +36,17 @@ def test_every_cell_resolves_its_files_by_name():
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
 
 
+def test_every_configuration_names_its_own_files():
+    """A configuration is its file and the reference, work model and
+    test-sized copy that the file names."""
+    for c in SPEC["configs"]:
+        cfg = harness.load_json(ROOT / c["file"])
+        for key in ("reference", "work", "test_copy"):
+            assert (ROOT / cfg[key]).is_file(), (c["name"], key)
+        assert callable(harness.config_module(cfg, "reference").sizes)
+        assert callable(harness.config_module(cfg, "work").flops_per_token)
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_harness_loop_runs_each_cell_at_test_size(name):
     cell = tiny_cell(name)
@@ -80,7 +91,6 @@ def synthetic_ctx(name):
     grads program (with one flash and one SSD kernel call) and a bucket
     update; the failure's span is mostly idle."""
     from bench import trace
-    from bench.peaks import peaks
     from bench.trace import Event
     dev, host = "/device:TPU:0", "/host:CPU"
     ms = 1_000_000
@@ -110,10 +120,9 @@ def synthetic_ctx(name):
               "events": [{"kind": "fail", "t_call": 0.5, "t_bound": 0.7,
                           "replan_s": 0.001, "copied_bytes": 1 << 30,
                           "first_step_end": 1.0}]}
-    return {"cell": cell, "config": cell.config,
-            "trace": trace.Reduction(events, programs), "window": window,
-            "peaks": peaks("TPU v5 lite"), "chips": cell.chips,
-            "memory_peak_bytes": 10_000_000_000}
+    return harness.reader_context(cell, window,
+                                  trace.Reduction(events, programs),
+                                  "TPU v5 lite", 10_000_000_000)
 
 
 @pytest.mark.parametrize("name", CELLS)

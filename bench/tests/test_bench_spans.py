@@ -56,6 +56,26 @@ def test_program_spans_name_the_idle_gaps():
     assert red.open_span(520 * MS) == "bench.recover"
 
 
+def test_a_recorded_trace_keeps_the_program_spans(tmp_path):
+    """``load_events`` on a profiler trace recorded on the CPU keeps the
+    benchmark's and the program's host spans, and drops other host
+    annotations."""
+    import jax
+    import jax.numpy as jnp
+    from repro.utils.spans import span
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            with span("oobleck.step.inputs"):
+                with jax.profiler.TraceAnnotation("other.annotation"):
+                    jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    names = {e.name for e in trace.load_events(trace.find_xplane(tmp_path))
+             if not e.plane.startswith(trace.DEVICE_PLANE)}
+    assert names == {"bench.window", "oobleck.step.inputs"}
+
+
 def steady_ctx(events, steps=2):
     programs = {"grads": [{"module": "jit_grads_fn", "calls": {}}],
                 "bscale": [{"module": "jit_bucket_scale", "calls": {}}],

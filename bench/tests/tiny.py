@@ -6,23 +6,25 @@ from pathlib import Path
 from bench import harness
 
 ROOT = Path(__file__).resolve().parents[2]
-TINY = {"gpt3_medium": "tiny_dense", "mamba2_780m": "tiny_ssm"}
 
 
-def tiny_spec():
-    """BENCHMARK.json with each configuration swapped for its test-sized
-    copy in ``bench/tests/data`` (same family, kernels and deployment)."""
-    with open(ROOT / "BENCHMARK.json") as f:
+def tiny_spec(root=ROOT):
+    """``BENCHMARK.json`` under ``root`` with each configuration swapped
+    for the test-sized copy that its file names (``test_copy``: the same
+    kernels and deployment)."""
+    with open(root / "BENCHMARK.json") as f:
         spec = copy.deepcopy(json.load(f))
     for c in spec["configs"]:
-        c["file"] = f"bench/tests/data/{TINY[c['name']]}.json"
+        c["file"] = harness.load_json(root / c["file"])["test_copy"]
     return spec
 
 
-def tiny_cell(name):
-    """The cell at test size, held to the committed configuration's
-    correctness limits."""
-    cell = harness.resolve_cell(name, spec=tiny_spec())
-    committed = harness.resolve_cell(name).config
-    cell.config["limits"] = dict(committed["limits"])
+def tiny_cell(name, root=ROOT, committed_limits=True):
+    """The cell at test size, with the committed configuration's
+    reference and work model and, unless ``committed_limits`` is false,
+    its correctness limits (else the test-sized copy's own)."""
+    cell = harness.resolve_cell(name, root, spec=tiny_spec(root))
+    committed = harness.resolve_cell(name, root).config
+    keys = ["reference", "work"] + ["limits"] * committed_limits
+    cell.config.update({k: copy.deepcopy(committed[k]) for k in keys})
     return cell

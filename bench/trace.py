@@ -14,7 +14,9 @@ same code runs on hand-placed events in the tests.
 * Kernel time is the summed duration of the Pallas custom calls that a
   program's compiled HLO makes to a kernel module's entry functions.
 * Idle gaps are the stretches of the window with no device operation,
-  each named by the innermost ``bench.*`` host span open at its start.
+  each named by the innermost host span open at its start: the
+  benchmark's own ``bench.*`` spans or the program's ``oobleck.*``
+  spans (``repro.utils.spans``).
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ DEVICE_PLANE = "/device:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 WINDOW_SPAN = "bench.window"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "oobleck.")
 
 
 @dataclasses.dataclass
@@ -55,8 +57,9 @@ def find_xplane(trace_dir: Path) -> Path:
 
 
 def load_events(path: Path) -> List[Event]:
-    """Device events of every ``/device:`` plane and the benchmark's own
-    host spans; other host events are dropped."""
+    """Device events of every ``/device:`` plane, and the host spans of
+    the benchmark and of the program (``SPAN_PREFIX``); other host
+    events are dropped."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(str(path))
     out: List[Event] = []
@@ -208,7 +211,7 @@ class Reduction:
         return sorted(named, key=lambda g: -g[1])
 
     def open_span(self, t: int) -> str:
-        """The innermost bench.* span open at ``t``."""
+        """The innermost host span open at ``t``."""
         return innermost([e for e in self.spans if e.start <= t < e.end])
 
     # -- programs and kernels ----------------------------------------------
